@@ -26,7 +26,7 @@ from benchmarks.common import Row, timed
 from repro.core import dns, queueing, scenario as scn_mod, storage_sim, \
     threshold
 from repro.core.scenario import Scenario
-from repro.kernels.cell_update import resolve_kernel_mode
+from repro.kernels.cell_update import kernel_path_mode, resolve_kernel_mode
 
 SYSTEMS = ("disk", "memcached", "dns")
 
@@ -84,7 +84,7 @@ def run(smoke: bool = False, mesh=None, kernel: str = "auto") -> list[Row]:
     # scan-vs-kernel parity on the mixed grid (interpreted off-TPU so a
     # kernel-path measurement always exists); smoke-sized — parity is a
     # contract check, not a timing row.
-    mode = resolved if resolved != "off" else resolve_kernel_mode("on")
+    mode = resolved if resolved != "off" else kernel_path_mode()
     pcfg = queueing.SimConfig(n_servers=20, n_arrivals=2_000)
     prhos = jnp.asarray([0.1, 0.3])
     off = queueing.run(key, scns, prhos, pcfg, n_seeds=1, kernel="off")

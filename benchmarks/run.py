@@ -29,6 +29,11 @@ mode for kernel-aware modules (``sweep_engine``, ``fig_policy_space``;
 field records the RESOLVED mode the row actually executed under
 (``on`` / ``off`` / ``interpret``, ``null`` for non-engine rows), so
 trajectories never mix kernel-path and scan-path numbers silently.
+Every row also names the device it ran on (``device_kind``), and the
+harness exits non-zero when any module raised — after writing the rows
+it did collect, the failing module's as an ``ERROR`` row. Compiled
+programs persist in the one compile-cache directory
+(``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -63,6 +68,9 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mesh = None
     if args.devices:
         # clamp to the largest DIVISOR of the visible device count:
@@ -93,11 +101,13 @@ def main() -> None:
                serving_hedge, roofline]
 
     provenance = {"backend": jax.default_backend(),
+                  "device_kind": jax.devices()[0].device_kind,
                   "device_count": jax.device_count(),
                   "process_count": jax.process_count()}
 
     print("name,us_per_call,derived")
     collected: list[dict[str, object]] = []
+    failed: list[str] = []
     t0 = time.time()
     for mod in modules:
         name = mod.__name__.split(".")[-1]
@@ -125,7 +135,8 @@ def main() -> None:
                                   "kernel": row_kernel,
                                   "sampling": row_sampling,
                                   **provenance})
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # keep the harness going, fail at exit
+            failed.append(name)
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
             collected.append({"name": f"{name}/ERROR", "us_per_call": 0,
                               "derived": f"{type(e).__name__}:{e}",
@@ -140,6 +151,8 @@ def main() -> None:
             json.dump(collected, f, indent=1)
         print(f"# wrote {len(collected)} rows to {args.json}",
               file=sys.stderr)
+    if failed:
+        sys.exit(f"# {len(failed)} module(s) raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

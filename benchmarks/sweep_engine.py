@@ -27,8 +27,9 @@ by the benchmark — a violation must still produce rows).
 Part 4 — fused cell-update kernel on vs off (``kernel`` argument, wired
 through ``run.py --kernel``): the same chunked sweep through the scan
 body (``kernel="off"``) and through the Pallas kernel path (the
-RESOLVED requested mode; off-TPU ``"on"`` degrades to ``"interpret"``
-so a measurement always exists), wall clock both ways, bit-identity
+RESOLVED requested mode; where that is the scan body, the kernel path
+this host can run — ``"on"`` on a TPU, ``"interpret"`` elsewhere — so a
+kernel-path row always exists), wall clock both ways, bit-identity
 recorded. The ``sweep_engine/kernel_on_vs_off`` row's derived field
 carries ``scan_s= / kernel_s= / speedup=`` so BENCH_*.json trajectories
 hold the measured kernel speedup as provenance; its 6th row element
@@ -58,7 +59,7 @@ from benchmarks.common import Row
 from repro.core import distributions as dists
 from repro.core import queueing, scenario as scn_mod, threshold
 from repro.core.scenario import Scenario
-from repro.kernels.cell_update import resolve_kernel_mode
+from repro.kernels.cell_update import kernel_path_mode, resolve_kernel_mode
 
 CFG = queueing.SimConfig(n_servers=20, n_arrivals=50_000)
 
@@ -163,12 +164,11 @@ def _kernel_rows(key, cfg: queueing.SimConfig, kernel: str,
     and for the kernel path on the same chunked sweep, bit-identity
     recorded, measured speedup in the derived field (JSON provenance).
     """
-    # off-TPU an "on"/"auto" request resolves to "off"/"interpret"; force
-    # the interpreter leg in that case so the row always holds a real
-    # kernel-path measurement.
+    # "auto" resolves to "off" off-TPU; take the interpreter leg then so
+    # the row always holds a kernel-path measurement.
     mode = resolve_kernel_mode(kernel)
     if mode == "off":
-        mode = resolve_kernel_mode("on")  # "on" on TPU, else "interpret"
+        mode = kernel_path_mode()  # "on" on TPU, else "interpret"
     scn = Scenario.paper_default(dists.exponential(), ks=(1, 2))
     rhos = jnp.linspace(0.1, 0.4, 3)
     kw = dict(n_seeds=2, chunk_size=CHUNK)

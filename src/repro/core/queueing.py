@@ -150,12 +150,14 @@ Pallas kernel (``repro.kernels.cell_update.kernel``) that keeps each
 cell's free-time grid, Kahan state and histogram counts resident in
 VMEM across the whole chunk, writing carry to HBM once per chunk
 instead of once per arrival. ``run(..., kernel=...)`` takes
-``"auto"`` (kernel on TPU, scan elsewhere), ``"on"``, ``"off"`` or
-``"interpret"`` (the kernel through the Pallas interpreter — how CPU
-CI bit-tests the kernel path); the sharded executor threads the same
+``"auto"`` (kernel on TPU, scan elsewhere), ``"on"`` (the compiled
+kernel; raises without a TPU), ``"off"`` or ``"interpret"`` (the
+kernel through the Pallas interpreter — how CPU CI bit-tests the
+kernel path); the sharded executor threads the same
 mode through ``shard_map``, preserving sharded==unsharded
 bit-identity in every mode. Kernel mode pads every chunk to a
-sketch-block multiple (scan mode only pads when the sketch is on) —
+lane-aligned sketch-block multiple (scan mode only pads when the
+sketch is on) —
 legal because zero-weight steps are bitwise no-ops on all carry state
 (see ``ref.kahan_fold``), so padded and unpadded layouts agree bit
 for bit. The step physics lives ONCE in
@@ -590,12 +592,15 @@ def _chunk_layout(cfg: SimConfig, chunk_size: int | None, need_hist: bool,
 
     Chunks are padded to a block multiple when the sketch needs staged
     sub-blocks OR the Pallas cell-update kernel is on (its time grid is
-    blocked unconditionally). Padding never changes bits: zero-weight
-    steps are bitwise no-ops on the whole carry (``ref.kahan_fold``)."""
+    blocked unconditionally, in lane-aligned blocks). Padding never
+    changes bits: zero-weight steps are bitwise no-ops on the whole
+    carry (``ref.kahan_fold``)."""
     m = cfg.n_arrivals
     t_chunk = m if chunk_size is None else min(int(chunk_size), m)
     n_chunks = math.ceil(m / t_chunk)
     block = min(_SKETCH_BLOCK, t_chunk)
+    if kernel_on:
+        block = -(-block // hist_ops.LANE) * hist_ops.LANE
     pad = (-t_chunk) % block if (need_hist or kernel_on) else 0
     return t_chunk, n_chunks, block, pad
 
@@ -1064,7 +1069,8 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
     kwargs = dict(variants=variants, warmup_frac=warmup_frac,
                   percentiles=tuple(percentiles), n_bins=n_bins,
                   chunk_size=chunk_size,
-                  use_kernel=cell_ops.resolve_kernel_mode(kernel),
+                  use_kernel=cell_ops.resolve_kernel_mode(
+                      kernel, n_bins=n_bins if percentiles else None),
                   pipeline=pipeline)
     if mesh is not None:
         from repro.distributed.sweep_shard import _sweep_cells_sharded

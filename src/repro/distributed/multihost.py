@@ -66,11 +66,9 @@ def initialize(coordinator_address: str | None = None,
 
     import jax
 
-    try:  # CPU collectives: gloo (the only CPU backend with cross-host
-        # all-gather support); unavailable names just keep the default
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - older/newer jax config names
-        pass
+    # CPU collectives: gloo (the only CPU backend with cross-host
+    # all-gather support)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes), process_id=int(process_id))

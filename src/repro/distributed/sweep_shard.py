@@ -101,7 +101,6 @@ finalize reads the addressable shards directly).
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -114,24 +113,7 @@ from repro.core.distributions import ServiceDist
 from repro.distributed import multihost
 from repro.launch.mesh import make_sweep_mesh
 
-try:  # public API (jax >= 0.6); the experimental module was removed
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
 Array = jax.Array
-
-
-def _shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off: pallas_call (the
-    hist_sketch kernel) has no replication rule, and every spec we pass
-    is explicit — nothing is inferred."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
 
 
 @functools.lru_cache(maxsize=None)
@@ -175,10 +157,12 @@ def _body_fn(mesh: jax.sharding.Mesh, n_servers: int, n_bins: int,
             has_timed=has_timed, has_dists=has_dists)
 
     cells = P("cells")
-    return jax.jit(_shard_map_unchecked(
-        chunk_body, mesh,
+    # replication checking off: pallas_call (the kernels) has no
+    # replication rule, and every spec here is explicit
+    return jax.jit(jax.shard_map(
+        chunk_body, mesh=mesh,
         in_specs=(cells,) * 20 + (P(),) * 3,
-        out_specs=(cells,) * 5))
+        out_specs=(cells,) * 5, check_vma=False))
 
 
 def _sweep_cells_sharded(sampler, n_seeds_total: int,

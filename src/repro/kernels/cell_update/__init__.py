@@ -10,6 +10,7 @@ between the two behind its ``use_kernel`` flag.
 """
 from repro.kernels.cell_update.ops import (cell_update,  # noqa: F401
                                            cell_update_costs,
+                                           kernel_path_mode,
                                            resolve_kernel_mode)
 from repro.kernels.cell_update.ref import (cell_update_ref,  # noqa: F401
                                            step_cell)

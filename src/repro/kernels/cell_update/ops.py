@@ -6,39 +6,36 @@ the benchmarks' ``--kernel`` flag):
 
   ``"off"``        the ``lax.scan`` reference body (``ref``) — the
                    default everywhere off-TPU.
-  ``"on"``         the compiled Pallas kernel (TPU).
+  ``"on"``         the compiled Pallas kernel. Needs a TPU: off-TPU it
+                   raises rather than quietly interpreting.
   ``"interpret"``  the Pallas kernel through the interpreter — same
-                   jnp ops, runs anywhere; bit-exact vs both other
-                   modes, so CPU/CI can test the kernel path.
-  ``"auto"``       resolves to ``"on"`` on TPU, ``"off"`` elsewhere.
-
-Requesting ``"on"`` off-TPU degrades to ``"interpret"`` (there is no
-TPU to compile for), so ``kernel="on"`` is always safe to pass.
+                   jnp ops, runs anywhere; bit-exact vs the scan body,
+                   so CPU/CI can test the kernel path.
+  ``"auto"``       resolves to ``"on"`` on TPU, ``"off"`` elsewhere —
+                   and to ``"off"`` when the sketch's ``n_bins`` is not
+                   a multiple of the 128 lane width. Only ``"auto"``
+                   may pick the reference; an explicit kernel mode the
+                   kernel cannot serve raises.
 """
 from __future__ import annotations
 
 import jax
 
 from repro.kernels.cell_update.kernel import cell_update_tc
-from repro.kernels.cell_update.ref import cell_update_ref
 from repro.kernels.hist_sketch.kernel import LANE
+from repro.kernels.hist_sketch.ops import on_tpu
 
 KERNEL_MODES = ("auto", "on", "off", "interpret")
 
-_ON_TPU = None
 
-
-def _on_tpu() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.devices()[0].platform == "tpu"
-    return _ON_TPU
-
-
-def resolve_kernel_mode(kernel: str | bool | None = "auto") -> str:
+def resolve_kernel_mode(kernel: str | bool | None = "auto", *,
+                        n_bins: int | None = None) -> str:
     """Normalize a ``kernel=`` knob to a concrete mode: ``"on"``,
     ``"off"`` or ``"interpret"`` (never ``"auto"``). Accepts the string
-    modes plus ``None``/``False`` (off) and ``True`` (on)."""
+    modes plus ``None``/``False`` (off) and ``True`` (on). ``n_bins`` is
+    the sketch width of a run that keeps percentiles (None: no sketch).
+    Raises for ``"on"`` without a TPU and for a kernel mode with a
+    sketch width the kernel cannot lay out."""
     if kernel is None or kernel is False:
         return "off"
     if kernel is True:
@@ -46,11 +43,26 @@ def resolve_kernel_mode(kernel: str | bool | None = "auto") -> str:
     if kernel not in KERNEL_MODES:
         raise ValueError(
             f"kernel must be one of {KERNEL_MODES}, got {kernel!r}")
+    aligned = n_bins is None or n_bins % LANE == 0
     if kernel == "auto":
-        return "on" if _on_tpu() else "off"
-    if kernel == "on" and not _on_tpu():
-        return "interpret"
+        return "on" if on_tpu() and aligned else "off"
+    if kernel != "off" and not aligned:
+        raise ValueError(
+            f"kernel={kernel!r} needs n_bins % {LANE} == 0, got "
+            f"n_bins={n_bins}; use kernel='auto' or 'off'")
+    if kernel == "on" and not on_tpu():
+        raise RuntimeError(
+            "kernel='on' needs a TPU; use kernel='interpret' to run the "
+            "Pallas kernel through the interpreter")
     return kernel
+
+
+def kernel_path_mode() -> str:
+    """The kernel-path mode a measurement can always take here: the
+    compiled kernel on a TPU, the interpreter elsewhere. For benchmark
+    rows that exist to exercise the kernel path and record which mode
+    ran; the engine itself never picks the interpreter."""
+    return "on" if on_tpu() else "interpret"
 
 
 def cell_update(free, ssum, comp, cnt, hist, cum, warm, valid, servers,
@@ -69,26 +81,22 @@ def cell_update(free, ssum, comp, cnt, hist, cum, warm, valid, servers,
     per-cell copy COUNT — an int the kernel prefetches and re-expands
     with an iota compare (boolean, no rounding). The degradation /
     timed-policy parameters (``p_slow``/``slow_factor``/``p_fail``/
-    ``delay``) prefetch as-is; ``has_timed`` only routes the scan
-    fallback (the kernel's timed ops are always compiled — scalar
-    selects keep them inert and bit-invisible for non-timed cells). A
-    sketch whose ``n_bins`` is not a multiple of the 128 lane width
-    falls back to the reference body (same bits, no kernel).
+    ``delay``) prefetch as-is; ``has_timed`` is accepted for signature
+    parity with the scan body only (the kernel's timed ops are always
+    compiled — scalar selects keep them inert and bit-invisible for
+    non-timed cells).
+    Raises for a sketch whose ``n_bins`` is not a multiple of the 128
+    lane width and for a chunk not padded to a lane-aligned block.
     """
     t_total = cum.shape[1]
-    need_hist = hist.size > 0
-    if need_hist and n_bins % LANE != 0:
-        return cell_update_ref(
-            free, ssum, comp, cnt, hist, cum, warm, valid, servers,
-            services, seed_idx, rates, k_mask, ovh, policy_code,
-            model_code, mix, p_slow, slow_factor, p_fail, delay, svc_idx,
-            n_bins=n_bins, block=block, has_shared=has_shared,
-            has_timed=has_timed, has_dists=has_dists)
-    if t_total % block != 0:
+    if hist.size > 0 and n_bins % LANE != 0:
+        raise ValueError(f"the cell_update kernel needs n_bins % {LANE} "
+                         f"== 0, got n_bins={n_bins}")
+    if t_total % block != 0 or block % LANE != 0:
         raise ValueError(
-            f"kernel mode needs the chunk padded to the block multiple "
-            f"(T={t_total}, block={block}); _chunk_layout pads when the "
-            f"kernel is on")
+            f"kernel mode needs the chunk padded to a multiple of a "
+            f"lane-aligned block (T={t_total}, block={block}); "
+            f"_chunk_layout arranges both when the kernel is on")
     k_count = k_mask.astype(jax.numpy.int32).sum(axis=1)
     return cell_update_tc(
         free, ssum, comp, cnt, hist, cum, warm, valid, servers, services,
@@ -108,8 +116,8 @@ def cell_update_costs(*, n_cells: int, n_servers: int, k_max: int,
     Per arrival per cell the step body costs ~``k_max * (3 * n_servers
     + 12) + 10`` flops (one-hot gather + scatter dominate at
     ``O(k * N)``; the selects/compares of the policy branches are the
-    rest), plus ``2 * n_bins`` MAC-flops per histogrammed arrival for
-    the indicator matmuls. HBM bytes count one read+write of the
+    rest), plus ``2 * n_bins`` flops per histogrammed arrival for the
+    one-hot bin add over the (n_bins / 128, 128) accumulator. HBM bytes count one read+write of the
     per-cell carry per chunk plus one pass over the seed-level sampled
     inputs — the kernel's whole point is that the carry term is per
     CHUNK, not per arrival.

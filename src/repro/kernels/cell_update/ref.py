@@ -191,10 +191,13 @@ def step_cell(free: Array, t: Array, srv: Array, svc: Array,
     return free, resp
 
 
-def kahan_fold(ssum: Array, comp: Array, resp: Array,
-               w: Array) -> tuple[Array, Array]:
+def kahan_fold(ssum: Array, comp: Array, resp: Array, w: Array, *,
+               barrier: bool = True) -> tuple[Array, Array]:
     """One gated Kahan step, shared verbatim by the scan body and the
-    Pallas kernel (same ops => same bits in both).
+    Pallas kernel (same ops => same bits in both). ``barrier=False`` is
+    for the compiled Pallas kernel only: Mosaic has no lowering for
+    ``optimization_barrier`` and no algebraic simplifier to guard
+    against.
 
     Kahan-compensated sum: sequential f32 accumulation over ~1e5+
     terms would otherwise cost ~1e-4 relative error on the mean,
@@ -216,7 +219,8 @@ def kahan_fold(ssum: Array, comp: Array, resp: Array,
     """
     y = resp - comp
     tot = ssum + y
-    tot_b, y_b = jax.lax.optimization_barrier((tot, y))
+    tot_b, y_b = (jax.lax.optimization_barrier((tot, y)) if barrier
+                  else (tot, y))
     comp_new = (tot_b - ssum) - y_b
     live = w > 0
     return jnp.where(live, tot_b, ssum), jnp.where(live, comp_new, comp)

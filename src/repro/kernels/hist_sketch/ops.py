@@ -1,5 +1,5 @@
-"""Public histogram-sketch ops: log binning, padded kernel dispatch with
-interpret-mode fallback on CPU, and percentile read-out.
+"""Public histogram-sketch ops: log binning, padded kernel dispatch, and
+percentile read-out.
 
 This package owns the sketch geometry (``HIST_LO`` / ``HIST_HI`` /
 ``DEFAULT_BINS``): ``n_bins`` log-spaced buckets spanning [HIST_LO,
@@ -19,14 +19,10 @@ HIST_LO = 1e-3
 HIST_HI = 1e5
 DEFAULT_BINS = 2048
 
-_ON_TPU = None
-
-
-def _interpret_default() -> bool:
-    global _ON_TPU
-    if _ON_TPU is None:
-        _ON_TPU = jax.devices()[0].platform == "tpu"
-    return not _ON_TPU
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU (the compiled kernels'
+    only target)."""
+    return jax.default_backend() == "tpu"
 
 
 def _log_scale(n_bins: int, lo: float, hi: float):
@@ -56,16 +52,26 @@ def hist_accum(idx: jax.Array, *, n_bins: int = DEFAULT_BINS,
                interpret: bool | None = None) -> jax.Array:
     """idx (T, C) int32 in [-1, n_bins) -> per-cell counts (C, n_bins) f32.
 
-    Pads the step axis up to a multiple of ``block_t`` with skip entries
-    and dispatches the Pallas kernel (interpret mode off-TPU). ``n_bins``
-    not divisible by the 128 lane width falls back to the jnp reference.
+    Pads the step axis up to a multiple of the (lane-aligned) block with
+    skip entries and dispatches the Pallas kernel. ``interpret``: None
+    picks the compiled kernel on a TPU and the interpreter elsewhere,
+    and the jnp reference when ``n_bins`` is not a multiple of the 128
+    lane width; True runs the interpreter; False the compiled kernel,
+    which raises without a TPU. Only None may choose the reference: an
+    explicit kernel request with unaligned ``n_bins`` raises.
     """
-    if interpret is None:
-        interpret = _interpret_default()
     if n_bins % LANE != 0:
-        return hist_accum_ref(idx, n_bins=n_bins)
+        if interpret is None:
+            return hist_accum_ref(idx, n_bins=n_bins)
+        raise ValueError(f"the hist_sketch kernel needs n_bins % {LANE} "
+                         f"== 0, got n_bins={n_bins}")
+    if interpret is None:
+        interpret = not on_tpu()
+    elif not interpret and not on_tpu():
+        raise RuntimeError("the compiled hist_sketch kernel needs a TPU; "
+                           "pass interpret=True to run the interpreter")
     t, _ = idx.shape
-    bt = min(block_t, t) if t % block_t else block_t
+    bt = -(-min(block_t, t) // LANE) * LANE
     pad = (-t) % bt
     if pad:
         idx = jnp.concatenate(

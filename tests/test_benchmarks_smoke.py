@@ -171,3 +171,64 @@ def test_fig12_accepts_chunked_engine_config():
 def test_run_harness_importable():
     import benchmarks.run as run_mod
     assert callable(run_mod.main)
+
+
+def test_run_harness_exits_nonzero_when_a_module_raises(monkeypatch,
+                                                        capsys):
+    """A module that raises still leaves its ERROR row, and the harness
+    exits non-zero instead of reporting success."""
+    import benchmarks.run as run_mod
+    import benchmarks.tab_tcp as tab_tcp
+    from repro.launch import compile_cache
+
+    def boom(smoke=False):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(tab_tcp, "run", boom)
+    monkeypatch.setattr(sys, "argv", ["run.py", "--only", "tab_tcp"])
+    with pytest.raises(SystemExit) as exc:
+        run_mod.main()
+    assert exc.value.code not in (0, None)
+    assert "tab_tcp/ERROR" in capsys.readouterr().out
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Off the TPU the rows carry the cost model and say "not measured";
+    a device kind without published peaks is an error, not a default."""
+    import types
+
+    import benchmarks.roofline as rl
+    for row in rl.run(smoke=True)[:2]:
+        assert "not measured" in row[2] and "peak_frac" not in row[2]
+    v5e = rl.device_peaks(types.SimpleNamespace(device_kind="TPU v5 lite"))
+    assert v5e == {"flops": 197e12, "hbm_bw": 819e9}
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.device_peaks(types.SimpleNamespace(device_kind="TPU v9"))
+
+
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    otherwise the one fixed directory is <repo>/.jax_cache."""
+    import jax
+
+    from repro.launch import compile_cache
+    repo = Path(__file__).resolve().parent.parent
+    assert compile_cache.DEFAULT_DIR == repo / ".jax_cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_chip_smoke_refuses_without_tpu(capsys):
+    """No TPU: the chip smoke test fails before any phase and prints no
+    result line."""
+    import jax
+
+    import chip_smoke
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("checks the refusal off the TPU")
+    with pytest.raises(SystemExit, match="no TPU"):
+        chip_smoke.main([])
+    assert '"ok"' not in capsys.readouterr().out

@@ -1,9 +1,10 @@
 """Fused Pallas cell-update kernel: bit-identity against the scan body.
 
 The contract (``repro.kernels.cell_update``): for the same inputs the
-kernel path (``kernel="on"`` / ``"interpret"`` — on CPU both run the
-Pallas interpreter, same jnp ops) and the ``lax.scan`` reference
-(``kernel="off"``) agree BIT FOR BIT — every policy x service-model
+kernel path through the Pallas interpreter (``kernel="interpret"``,
+the same jnp ops as the compiled kernel; ``kernel="on"`` compiles it
+and needs a TPU) and the ``lax.scan`` reference (``kernel="off"``)
+agree BIT FOR BIT — every policy x service-model
 code, mixed grids, pad cells, chunked and unchunked layouts, histogram
 on and off. On CPU the kernel runs in interpret mode, which is exactly
 why these tests can pin the contract in every tier-1 run; the sharded
@@ -34,21 +35,32 @@ def _assert_bits(a, b, fields=("mean", "p50", "p99")):
 
 def _both(key, scn, rhos, cfg, **kw):
     off = queueing.run(key, scn, rhos, cfg, kernel="off", **kw)
-    on = queueing.run(key, scn, rhos, cfg, kernel="on", **kw)
+    on = queueing.run(key, scn, rhos, cfg, kernel="interpret", **kw)
     return off, on
 
 
 class TestKernelModeResolution:
     def test_auto_off_tpu_is_off(self):
-        # this suite runs on CPU: auto must stay on the scan body
+        # this suite runs on CPU: auto must stay on the scan body, and
+        # "on" must refuse rather than quietly interpret
         assert cell_ops.resolve_kernel_mode("auto") in ("off", "on")
         if jax.devices()[0].platform != "tpu":
             assert cell_ops.resolve_kernel_mode("auto") == "off"
-            assert cell_ops.resolve_kernel_mode("on") == "interpret"
+            for on in ("on", True):
+                with pytest.raises(RuntimeError, match="TPU"):
+                    cell_ops.resolve_kernel_mode(on)
+            with pytest.raises(RuntimeError, match="TPU"):
+                queueing.run(jax.random.PRNGKey(0),
+                             Scenario.paper_default(dists.exponential()),
+                             RHOS, CFG, kernel="on")
         assert cell_ops.resolve_kernel_mode("off") == "off"
         assert cell_ops.resolve_kernel_mode("interpret") == "interpret"
         assert cell_ops.resolve_kernel_mode(None) == "off"
         assert cell_ops.resolve_kernel_mode(False) == "off"
+        # only "auto" may pick the reference for an unaligned sketch
+        assert cell_ops.resolve_kernel_mode("auto", n_bins=100) == "off"
+        with pytest.raises(ValueError, match="n_bins"):
+            cell_ops.resolve_kernel_mode("interpret", n_bins=100)
 
     def test_rejects_unknown_mode(self):
         with pytest.raises(ValueError, match="kernel"):
@@ -104,9 +116,12 @@ class TestKernelParity:
         _assert_bits(off, on, fields=("mean",))
 
     def test_interpret_equals_on(self):
+        # the kernel-path mode this host can run ("on" on a TPU, the
+        # interpreter elsewhere) against an explicit interpreter run
         key = jax.random.PRNGKey(4)
         scn = Scenario.paper_default(dists.weibull(0.7), ks=(1, 2))
-        on = queueing.run(key, scn, RHOS, CFG, n_seeds=1, kernel="on")
+        on = queueing.run(key, scn, RHOS, CFG, n_seeds=1,
+                          kernel=cell_ops.kernel_path_mode())
         interp = queueing.run(key, scn, RHOS, CFG, n_seeds=1,
                               kernel="interpret")
         _assert_bits(on, interp)
@@ -117,7 +132,7 @@ class TestKernelParity:
         t_off = threshold.threshold_bisect(key, dists.exponential(), CFG,
                                            kernel="off", **kw)
         t_on = threshold.threshold_bisect(key, dists.exponential(), CFG,
-                                          kernel="on", **kw)
+                                          kernel="interpret", **kw)
         assert t_off == t_on
 
 
@@ -167,9 +182,11 @@ class TestDeprecatedShims:
         key = jax.random.PRNGKey(7)
         with pytest.warns(DeprecationWarning, match="queueing.sweep"):
             shim = queueing.sweep(key, dists.exponential(), RHOS, CFG,
-                                  ks=(1, 2), n_seeds=1, kernel="on")
+                                  ks=(1, 2), n_seeds=1,
+                                  kernel="interpret")
         scn = Scenario.paper_default(dists.exponential(), ks=(1, 2))
-        direct = queueing.run(key, scn, RHOS, CFG, n_seeds=1, kernel="on")
+        direct = queueing.run(key, scn, RHOS, CFG, n_seeds=1,
+                              kernel="interpret")
         _assert_bits(shim, direct)
         # and the kernel path equals the scan path through the shim too
         with pytest.warns(DeprecationWarning):
@@ -183,10 +200,10 @@ class TestDeprecatedShims:
         with pytest.warns(DeprecationWarning, match="sweep_dists"):
             shim = queueing.sweep_dists(key, ds, RHOS, CFG, ks=(1, 2),
                                         n_seeds=1, percentiles=(),
-                                        kernel="on")
+                                        kernel="interpret")
         scn = Scenario.paper_default(ds, ks=(1, 2))
         direct = queueing.run(key, scn, RHOS, CFG, n_seeds=1,
-                              percentiles=(), kernel="on")
+                              percentiles=(), kernel="interpret")
         assert jnp.array_equal(shim["mean"], direct["mean"])
 
     def test_replication_gain_warns_and_matches_scan(self):
@@ -194,7 +211,7 @@ class TestDeprecatedShims:
         with pytest.warns(DeprecationWarning, match="replication_gain"):
             g_on = queueing.replication_gain(key, dists.exponential(),
                                              RHOS, CFG, n_seeds=1,
-                                             kernel="on")
+                                             kernel="interpret")
         with pytest.warns(DeprecationWarning):
             g_off = queueing.replication_gain(key, dists.exponential(),
                                               RHOS, CFG, n_seeds=1,

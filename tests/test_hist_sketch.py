@@ -55,10 +55,22 @@ class TestKernelParity:
         assert jnp.array_equal(out, ref.hist_accum_ref(idx, n_bins=1024))
 
     def test_non_lane_divisible_bins_falls_back_to_ref(self):
-        # n_bins not divisible by the 128 lane width cannot use the kernel
+        # n_bins not divisible by the 128 lane width cannot use the
+        # kernel: the automatic choice takes the reference, an explicit
+        # kernel request raises instead of quietly doing the same
         idx = _rand_idx(5, 300, 2, 100)
-        out = ops.hist_accum(idx, n_bins=100, interpret=True)
+        out = ops.hist_accum(idx, n_bins=100)
         assert jnp.array_equal(out, ref.hist_accum_ref(idx, n_bins=100))
+        for interpret in (True, False):
+            with pytest.raises(ValueError, match="n_bins"):
+                ops.hist_accum(idx, n_bins=100, interpret=interpret)
+
+    def test_compiled_kernel_off_tpu_raises(self):
+        if ops.on_tpu():
+            pytest.skip("checks the off-TPU refusal")
+        idx = _rand_idx(6, 256, 2, 128)
+        with pytest.raises(RuntimeError, match="TPU"):
+            ops.hist_accum(idx, n_bins=128, interpret=False)
 
     def test_warm_weights_encoded_as_skips(self):
         vals = jax.random.exponential(jax.random.PRNGKey(0), (600, 3)) + 1e-3

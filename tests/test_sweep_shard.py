@@ -125,8 +125,9 @@ class TestShardedSingleDeviceMesh:
         scn = Scenario.paper_default(dists.exponential(), ks=(1, 2))
         kw = dict(n_seeds=2, chunk_size=1_700)
         un_scan = queueing.run(key, scn, RHOS, CFG, kernel="off", **kw)
-        un_kern = queueing.run(key, scn, RHOS, CFG, kernel="on", **kw)
-        sh_kern = queueing.run(key, scn, RHOS, CFG, kernel="on",
+        un_kern = queueing.run(key, scn, RHOS, CFG, kernel="interpret",
+                               **kw)
+        sh_kern = queueing.run(key, scn, RHOS, CFG, kernel="interpret",
                                mesh=make_sweep_mesh(1), **kw)
         _assert_bit_identical(un_scan, un_kern)
         _assert_bit_identical(un_kern, sh_kern)
