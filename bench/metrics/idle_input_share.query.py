@@ -1,0 +1,14 @@
+"""Share of the window in which the devices sat idle, on average, while
+an engine call waited for its inputs: the ``repro.chunk.input`` spans,
+in an unchunked call its eager sampling (``bench/spans.py``). None where
+the program marks no ``repro.run`` span."""
+from pathlib import Path
+
+from bench import spans
+
+ROOT = Path(__file__).resolve().parents[2]
+
+
+def read(ctx):
+    win = spans.window_of(ctx, ROOT)
+    return None if win is None else win.idle_share_in(("repro.chunk.input",))

@@ -22,17 +22,23 @@ one (pinned by tests/test_multihost.py) — so ``pipeline="on"`` and
 ``pipeline="off"`` produce bit-identical summaries.
 
 ``PipelineStats`` records the last run's pipeline + sampling shape (per
-chunk: rows/bytes actually sampled vs the full input block) so the
-benchmark harness can carry per-host sampled-bytes provenance in
-BENCH_*.json rows (``stats_provenance``). This module is deliberately
-engine-agnostic (no ``queueing`` import): both execution layers feed it
-plain callables.
+chunk: rows/bytes actually sampled vs the full input block) and the
+seconds each side of the pipeline waited (``Waits``), so the benchmark
+harness can carry per-host sampled-bytes provenance in BENCH_*.json rows
+(``stats_provenance``). Each wait is also a profiler span,
+``repro.chunk.input`` on the consumer and ``repro.chunk.slot`` on the
+producer, tagged with the caller's engine-call id and the chunk. This
+module is deliberately engine-agnostic (no ``queueing`` import): both
+execution layers feed it plain callables.
 """
 from __future__ import annotations
 
 import dataclasses
 import queue as queue_mod
 import threading
+import time
+
+from jax.profiler import TraceAnnotation
 
 # Staging slots the producer may fill ahead of the consumer (double
 # buffering). More buys nothing: sampling one chunk is faster than
@@ -48,6 +54,7 @@ class PipelineStats:
     drew per chunk; ``*_rows_total`` the full block's rows (what every
     process sampled before the per-host reduction). ``bytes_*`` are the
     same reduction in bytes of the (gaps, servers, services) inputs.
+    ``input_wait_s`` / ``slot_wait_s`` are the run's ``Waits``.
     """
 
     enabled: bool
@@ -61,6 +68,8 @@ class PipelineStats:
     bytes_full_per_chunk: int
     process_count: int = 1
     process_index: int = 0
+    input_wait_s: float = 0.0
+    slot_wait_s: float = 0.0
 
     @property
     def locality_factor(self) -> float:
@@ -68,6 +77,17 @@ class PipelineStats:
         multi-host sampling reduction of the ISSUE's acceptance bar)."""
         return self.bytes_full_per_chunk / max(self.bytes_sampled_per_chunk,
                                                1)
+
+
+@dataclasses.dataclass
+class Waits:
+    """Seconds one ``iter_staged`` loop waited: the consumer for each
+    chunk's inputs (``input_s``: the queue when pipelined, the inline
+    ``produce`` when serial) and the producer for a free staging slot
+    (``slot_s``). Each side writes only its own field."""
+
+    input_s: float = 0.0
+    slot_s: float = 0.0
 
 
 _LAST_STATS: list[PipelineStats | None] = [None]
@@ -94,7 +114,8 @@ def stats_provenance() -> dict | None:
 
 
 def iter_staged(produce, n_chunks: int, *, depth: int = DEFAULT_DEPTH,
-                enabled: bool = True):
+                enabled: bool = True, call: int = -1,
+                waits: Waits | None = None):
     """Yield ``produce(c)`` for ``c in range(n_chunks)``, prefetching up
     to ``depth`` chunks ahead on a producer thread when ``enabled``.
 
@@ -111,10 +132,19 @@ def iter_staged(produce, n_chunks: int, *, depth: int = DEFAULT_DEPTH,
     ``enabled=False`` (or a single chunk, where there is nothing to
     overlap) degrades to the plain serial loop — the pipeline-off
     reference path.
+
+    Each wait is timed into ``waits`` (when given) and marked by a
+    ``repro.chunk.input`` / ``repro.chunk.slot`` span carrying ``call``
+    (the caller's engine-call id) and the chunk.
     """
+    waits = Waits() if waits is None else waits
     if not enabled or n_chunks <= 1 or depth < 1:
         for c in range(n_chunks):
-            yield produce(c)
+            t0 = time.perf_counter()
+            with TraceAnnotation("repro.chunk.input", call=call, chunk=c):
+                payload = produce(c)
+            waits.input_s += time.perf_counter() - t0
+            yield payload
         return
 
     free = threading.Semaphore(depth)       # staging slots (buffer pool)
@@ -123,9 +153,12 @@ def iter_staged(produce, n_chunks: int, *, depth: int = DEFAULT_DEPTH,
 
     def producer() -> None:
         for c in range(n_chunks):
-            while not free.acquire(timeout=0.1):
-                if stop.is_set():
-                    return
+            t0 = time.perf_counter()
+            with TraceAnnotation("repro.chunk.slot", call=call, chunk=c):
+                while not free.acquire(timeout=0.1):
+                    if stop.is_set():
+                        return
+            waits.slot_s += time.perf_counter() - t0
             if stop.is_set():
                 return
             try:
@@ -136,10 +169,16 @@ def iter_staged(produce, n_chunks: int, *, depth: int = DEFAULT_DEPTH,
 
     th = threading.Thread(target=producer, name="chunkflow-producer",
                           daemon=True)
-    th.start()
     try:
         for c in range(n_chunks):
-            got_c, payload, err = ready.get()
+            t0 = time.perf_counter()
+            with TraceAnnotation("repro.chunk.input", call=call, chunk=c):
+                if c == 0:
+                    # the wait for chunk 0 starts with its producer, which
+                    # may hold the interpreter lock until it has sampled
+                    th.start()
+                got_c, payload, err = ready.get()
+            waits.input_s += time.perf_counter() - t0
             assert got_c == c, (got_c, c)
             if err is not None:
                 raise err

@@ -168,12 +168,14 @@ layout / block-size / CRN-contract design note.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import warnings
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import cellplan
 from repro.core import chunkflow
@@ -191,6 +193,10 @@ from repro.launch import mesh as launch_mesh
 Array = jax.Array
 
 DEFAULT_PERCENTILES = (50.0, 90.0, 99.0, 99.9)
+
+# The engine-call id of ``run``'s profiler spans: one per call,
+# process-wide, so the spans of one call can be told from the next.
+_ENGINE_CALLS = itertools.count()
 
 # Scan steps staged per hist_sketch kernel call; chunk lengths are padded
 # up to a multiple of this with zero-weight no-op arrivals.
@@ -641,12 +647,13 @@ def _finalize_summary(plan: cellplan.CellPlan, ssum: Array, cnt: Array,
 
 
 def _record_pipeline_stats(sampler, *, enabled: bool, n_chunks: int,
-                           t_pad: int, seed_rows: int,
-                           svc_rows: int) -> None:
-    """Publish this run's pipeline + sampling shape to ``chunkflow`` so
-    the benchmark harness can attach it as JSON provenance. ``seed_rows``
-    / ``svc_rows`` are the rows THIS process sampled per chunk (the full
-    block on one process; the per-host reduction on many)."""
+                           t_pad: int, seed_rows: int, svc_rows: int,
+                           waits: chunkflow.Waits) -> None:
+    """Publish this run's pipeline + sampling shape and its chunk loop's
+    ``waits`` to ``chunkflow`` so the benchmark harness can attach them
+    as JSON provenance. ``seed_rows`` / ``svc_rows`` are the rows THIS
+    process sampled per chunk (the full block on one process; the
+    per-host reduction on many)."""
     spec = getattr(sampler, "spec", None)
     if spec is None:
         return
@@ -664,7 +671,8 @@ def _record_pipeline_stats(sampler, *, enabled: bool, n_chunks: int,
         bytes_sampled_per_chunk=nbytes(seed_rows, svc_rows),
         bytes_full_per_chunk=nbytes(spec.n_seed_rows, spec.n_svc_rows),
         process_count=jax.process_count(),
-        process_index=jax.process_index()))
+        process_index=jax.process_index(),
+        input_wait_s=waits.input_s, slot_wait_s=waits.slot_s))
 
 
 def _run_engine(sampler, n_seeds_total: int, rhos: Array, cfg: SimConfig, *,
@@ -672,7 +680,7 @@ def _run_engine(sampler, n_seeds_total: int, rhos: Array, cfg: SimConfig, *,
                 percentiles: tuple[float, ...],
                 n_bins: int, chunk_size: int | None,
                 use_kernel: str = "off",
-                pipeline: str = "off") -> dict[str, Array]:
+                pipeline: str = "off", call: int = -1) -> dict[str, Array]:
     """Drive ``_sweep_chunk_cells`` over the whole arrival stream on one
     device: unpadded cell plan (variant policy/model codes as per-cell
     coordinates), seed-level sampled inputs shared by each seed's
@@ -687,60 +695,69 @@ def _run_engine(sampler, n_seeds_total: int, rhos: Array, cfg: SimConfig, *,
     sampler's FUSED jit entry point, one dispatch per chunk — while the
     chunk body for ``c`` runs (``repro.core.chunkflow``); bit-identical
     to ``"off"`` because it changes when inputs are sampled, never what.
+    ``call`` tags the ``repro.*`` profiler spans of the loop (the
+    engine-call id ``run`` assigns).
     """
     m = cfg.n_arrivals
-    policies, models = scenario_mod.variant_codes(variants)
-    plan = cellplan.make_cell_plan(
-        n_seeds_total, rhos.shape[0], len(variants),
-        policies=policies, models=models,
-        dist_ids=scenario_mod.variant_dist_ids(variants))
-    (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
-     delay_c) = _plan_cell_params(plan, rhos, cfg, variants)
-    has_shared = scenario_mod.any_server_dependent(variants)
-    has_timed = scenario_mod.any_timed(variants)
-    has_dists = scenario_mod.any_dist_ids(variants)
-    # heterogeneous grids: route each cell's service gather to its
-    # system's table row (services stacks one table per union member
-    # along the seed axis); None keeps the legacy jaxpr untouched
-    svc_idx_c = (plan.dist_id * n_seeds_total + plan.seed_idx
-                 if has_dists else None)
-    warmup_start = int(m * warmup_frac)
-    need_hist = len(percentiles) > 0
-    t_chunk, n_chunks, block, pad = _chunk_layout(
-        cfg, chunk_size, need_hist, kernel_on=use_kernel != "off")
-    free, ssum, comp, cnt, hist = _init_cell_state(plan, cfg, n_bins,
-                                                   need_hist)
+    with TraceAnnotation("repro.plan", call=call):
+        policies, models = scenario_mod.variant_codes(variants)
+        plan = cellplan.make_cell_plan(
+            n_seeds_total, rhos.shape[0], len(variants),
+            policies=policies, models=models,
+            dist_ids=scenario_mod.variant_dist_ids(variants))
+        (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
+         delay_c) = _plan_cell_params(plan, rhos, cfg, variants)
+        has_shared = scenario_mod.any_server_dependent(variants)
+        has_timed = scenario_mod.any_timed(variants)
+        has_dists = scenario_mod.any_dist_ids(variants)
+        # heterogeneous grids: route each cell's service gather to its
+        # system's table row (services stacks one table per union member
+        # along the seed axis); None keeps the legacy jaxpr untouched
+        svc_idx_c = (plan.dist_id * n_seeds_total + plan.seed_idx
+                     if has_dists else None)
+        warmup_start = int(m * warmup_frac)
+        need_hist = len(percentiles) > 0
+        t_chunk, n_chunks, block, pad = _chunk_layout(
+            cfg, chunk_size, need_hist, kernel_on=use_kernel != "off")
+        free, ssum, comp, cnt, hist = _init_cell_state(plan, cfg, n_bins,
+                                                       need_hist)
 
     use_pipe = pipeline == "on" and n_chunks > 1
     fused = getattr(sampler, "fused", None)
     draw = fused if (use_pipe and fused is not None) else sampler
 
     def produce(c: int):
-        return _pad_chunk_inputs(*draw(c, t_chunk), pad)
+        with TraceAnnotation("repro.sample", call=call, chunk=c):
+            return _pad_chunk_inputs(*draw(c, t_chunk), pad)
 
+    waits = chunkflow.Waits()
     for c, (unit_gaps, servers, services) in enumerate(
-            chunkflow.iter_staged(produce, n_chunks, enabled=use_pipe)):
+            chunkflow.iter_staged(produce, n_chunks, enabled=use_pipe,
+                                  call=call, waits=waits)):
         start = c * t_chunk
-        free, ssum, comp, cnt, hist = _sweep_chunk_cells(
-            free, ssum, comp, cnt, hist, unit_gaps, servers, services,
-            jnp.asarray(start), jnp.asarray(min(t_chunk, m - start)),
-            jnp.asarray(warmup_start), plan.seed_idx, rates_c, k_mask_c,
-            ovh_c, plan.policy_code, plan.model_code, mix_c, pslow_c,
-            sfac_c, pfail_c, delay_c, svc_idx_c,
-            n_servers=cfg.n_servers, n_bins=n_bins, block=block,
-            use_kernel=use_kernel, has_shared=has_shared,
-            has_timed=has_timed, has_dists=has_dists)
+        with TraceAnnotation("repro.chunk.dispatch", call=call, chunk=c):
+            free, ssum, comp, cnt, hist = _sweep_chunk_cells(
+                free, ssum, comp, cnt, hist, unit_gaps, servers, services,
+                jnp.asarray(start), jnp.asarray(min(t_chunk, m - start)),
+                jnp.asarray(warmup_start), plan.seed_idx, rates_c,
+                k_mask_c, ovh_c, plan.policy_code, plan.model_code, mix_c,
+                pslow_c, sfac_c, pfail_c, delay_c, svc_idx_c,
+                n_servers=cfg.n_servers, n_bins=n_bins, block=block,
+                use_kernel=use_kernel, has_shared=has_shared,
+                has_timed=has_timed, has_dists=has_dists)
     # block on the last chunk so the producer thread (if any) is drained
     # before stats are read, then record sampling provenance
-    jax.block_until_ready(ssum)
+    with TraceAnnotation("repro.drain", call=call):
+        jax.block_until_ready(ssum)
     spec = getattr(sampler, "spec", None)
     _record_pipeline_stats(
         sampler, enabled=use_pipe, n_chunks=n_chunks, t_pad=t_chunk + pad,
         seed_rows=spec.n_seed_rows if spec is not None else 0,
-        svc_rows=spec.n_svc_rows if spec is not None else 0)
+        svc_rows=spec.n_svc_rows if spec is not None else 0, waits=waits)
 
-    return _finalize_summary(plan, ssum, cnt, hist, m - warmup_start,
-                             percentiles)
+    with TraceAnnotation("repro.finalize", call=call):
+        return _finalize_summary(plan, ssum, cnt, hist, m - warmup_start,
+                                 percentiles)
 
 
 def _chunk_key(key: Array, chunk_idx: int, chunk_size: int | None) -> Array:
@@ -1033,6 +1050,11 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
 
     ``warmup_frac`` and ``client_overhead`` come from the Scenario, NOT
     from ``cfg`` (the legacy shims copy them over).
+
+    Each call is one ``repro.run`` profiler span, tagged with a
+    process-wide engine-call id (``call``) that the engine's inner
+    ``repro.*`` spans repeat, and with the call's shape: ``cells``,
+    ``chunks``, the resolved ``kernel`` and ``pipeline``, ``devices``.
     """
     dist_list, warmup_frac, variants = scenario_mod.combine(scenario)
     if pipeline not in ("auto", "on", "off"):
@@ -1042,47 +1064,58 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
         pipeline = ("on" if chunk_size is not None
                     and cfg.n_arrivals > int(chunk_size) else "off")
     mesh = launch_mesh.resolve_mesh(mesh)
-    rhos = jnp.asarray(rhos)
-    k_max = max(v.k for v in variants)
-    with_shared = scenario_mod.any_server_dependent(variants)
-    with_degr = scenario_mod.any_degraded(variants)
     has_dists = scenario_mod.any_dist_ids(variants)
     d = len(dist_list)
-    if d == 1:
-        sampler = _sweep_sampler(key, dist_list[0], cfg, k_max, n_seeds,
-                                 chunk_size, with_shared=with_shared,
-                                 with_degr=with_degr)
-    elif has_dists:
-        # heterogeneous grid: the dist union stacks service TABLES only;
-        # the plan's seed axis stays n_seeds and each cell routes to its
-        # system's table via its dist_id (no per-dist output axis — the
-        # variant axis already carries "which system")
-        sampler = _dist_table_sampler(key, dist_list, cfg, k_max, n_seeds,
-                                      chunk_size, with_shared=with_shared,
-                                      with_degr=with_degr)
-    else:
-        sampler = _sweep_dists_sampler(key, dist_list, cfg, k_max, n_seeds,
-                                       chunk_size, with_shared=with_shared,
-                                       with_degr=with_degr)
-
     n_seeds_total = n_seeds if has_dists else d * n_seeds
-    kwargs = dict(variants=variants, warmup_frac=warmup_frac,
-                  percentiles=tuple(percentiles), n_bins=n_bins,
-                  chunk_size=chunk_size,
-                  use_kernel=cell_ops.resolve_kernel_mode(
-                      kernel, n_bins=n_bins if percentiles else None),
-                  pipeline=pipeline)
-    if mesh is not None:
-        from repro.distributed.sweep_shard import _sweep_cells_sharded
-        out = _sweep_cells_sharded(sampler, n_seeds_total, rhos, cfg,
-                                   mesh=mesh, **kwargs)
-    else:
-        out = _run_engine(sampler, n_seeds_total, rhos, cfg, **kwargs)
-    if d > 1 and not has_dists:
-        out = {k: (v.reshape((d, n_seeds) + v.shape[1:])
-                   if isinstance(v, jax.Array) else v)
-               for k, v in out.items()}
-    return out
+    use_kernel = cell_ops.resolve_kernel_mode(
+        kernel, n_bins=n_bins if percentiles else None)
+    call = next(_ENGINE_CALLS)
+    with TraceAnnotation(
+            "repro.run", call=call,
+            cells=n_seeds_total * len(rhos) * len(variants),
+            chunks=_chunk_layout(cfg, chunk_size, need_hist=False)[1],
+            kernel=use_kernel, pipeline=pipeline,
+            devices=1 if mesh is None else mesh.devices.size):
+        rhos = jnp.asarray(rhos)
+        k_max = max(v.k for v in variants)
+        with_shared = scenario_mod.any_server_dependent(variants)
+        with_degr = scenario_mod.any_degraded(variants)
+        if d == 1:
+            sampler = _sweep_sampler(key, dist_list[0], cfg, k_max,
+                                     n_seeds, chunk_size,
+                                     with_shared=with_shared,
+                                     with_degr=with_degr)
+        elif has_dists:
+            # heterogeneous grid: the dist union stacks service TABLES
+            # only; the plan's seed axis stays n_seeds and each cell
+            # routes to its system's table via its dist_id (no per-dist
+            # output axis — the variant axis already carries "which
+            # system")
+            sampler = _dist_table_sampler(key, dist_list, cfg, k_max,
+                                          n_seeds, chunk_size,
+                                          with_shared=with_shared,
+                                          with_degr=with_degr)
+        else:
+            sampler = _sweep_dists_sampler(key, dist_list, cfg, k_max,
+                                           n_seeds, chunk_size,
+                                           with_shared=with_shared,
+                                           with_degr=with_degr)
+
+        kwargs = dict(variants=variants, warmup_frac=warmup_frac,
+                      percentiles=tuple(percentiles), n_bins=n_bins,
+                      chunk_size=chunk_size, use_kernel=use_kernel,
+                      pipeline=pipeline, call=call)
+        if mesh is not None:
+            from repro.distributed.sweep_shard import _sweep_cells_sharded
+            out = _sweep_cells_sharded(sampler, n_seeds_total, rhos, cfg,
+                                       mesh=mesh, **kwargs)
+        else:
+            out = _run_engine(sampler, n_seeds_total, rhos, cfg, **kwargs)
+        if d > 1 and not has_dists:
+            out = {k: (v.reshape((d, n_seeds) + v.shape[1:])
+                       if isinstance(v, jax.Array) else v)
+                   for k, v in out.items()}
+        return out
 
 
 def _warn_deprecated_shim(name: str) -> None:

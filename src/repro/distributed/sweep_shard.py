@@ -105,6 +105,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.core import cellplan, chunkflow, queueing
@@ -172,7 +173,8 @@ def _sweep_cells_sharded(sampler, n_seeds_total: int,
                          chunk_size: int | None,
                          mesh: jax.sharding.Mesh | None,
                          use_kernel: str = "off",
-                         pipeline: str = "off") -> dict[str, Array]:
+                         pipeline: str = "off",
+                         call: int = -1) -> dict[str, Array]:
     """Drive the shard_mapped chunk body over the whole arrival stream.
 
     ``sampler`` is the SAME ``queueing.ChunkSampler`` the unsharded
@@ -184,7 +186,10 @@ def _sweep_cells_sharded(sampler, n_seeds_total: int,
     MIXED-policy grids ride the same device-local body. ``pipeline`` is
     resolved (``"on"``/``"off"``): ``"on"`` overlaps next-chunk sampling
     + staging with the current chunk's compute via
-    ``chunkflow.iter_staged`` — bit-identical either way.
+    ``chunkflow.iter_staged`` — bit-identical either way. ``call``
+    tags the ``repro.*`` profiler spans, as in ``queueing._run_engine``;
+    ``repro.stage`` marks the host copy of the sampled rows and their
+    staging onto the devices.
     """
     mesh = make_sweep_mesh() if mesh is None else mesh
     if tuple(mesh.axis_names) != ("cells",):
@@ -196,105 +201,114 @@ def _sweep_cells_sharded(sampler, n_seeds_total: int,
             "the sharded executor needs a queueing.ChunkSampler "
             "(its .spec/.rows drive the per-host sampling reduction); "
             "got a bare sampler callable")
-    m = cfg.n_arrivals
-    variants = tuple(variants)
-    policies, models = scenario_mod.variant_codes(variants)
-    plan = cellplan.make_cell_plan(
-        n_seeds_total, rhos.shape[0], len(variants),
-        pad_to=mesh.devices.size, policies=policies, models=models,
-        dist_ids=scenario_mod.variant_dist_ids(variants))
-    rules = plan.sharding_rule(mesh)
-    (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
-     delay_c) = queueing._plan_cell_params(plan, rhos, cfg, variants)
-    has_shared = scenario_mod.any_server_dependent(variants)
-    has_timed = scenario_mod.any_timed(variants)
-    has_dists = scenario_mod.any_dist_ids(variants)
+    with TraceAnnotation("repro.plan", call=call):
+        m = cfg.n_arrivals
+        variants = tuple(variants)
+        policies, models = scenario_mod.variant_codes(variants)
+        plan = cellplan.make_cell_plan(
+            n_seeds_total, rhos.shape[0], len(variants),
+            pad_to=mesh.devices.size, policies=policies, models=models,
+            dist_ids=scenario_mod.variant_dist_ids(variants))
+        rules = plan.sharding_rule(mesh)
+        (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
+         delay_c) = queueing._plan_cell_params(plan, rhos, cfg, variants)
+        has_shared = scenario_mod.any_server_dependent(variants)
+        has_timed = scenario_mod.any_timed(variants)
+        has_dists = scenario_mod.any_dist_ids(variants)
 
-    # global input-row index per cell -> per-device row lists + local
-    # gather indices (exact remap; svc rows == seed rows unless the grid
-    # is heterogeneous, where services stack one table per union member)
-    n_dev = rules.n_devices
-    seed_g = np.asarray(plan.seed_idx)
-    seed_rows, seed_local = cellplan.device_row_maps(seed_g, n_dev)
-    if has_dists:
-        svc_rows, svc_local = cellplan.device_row_maps(
-            np.asarray(plan.dist_id) * n_seeds_total + seed_g, n_dev)
-    else:
-        svc_rows, svc_local = seed_rows, seed_local
+        # global input-row index per cell -> per-device row lists + local
+        # gather indices (exact remap; svc rows == seed rows unless the grid
+        # is heterogeneous, where services stack one table per union member)
+        n_dev = rules.n_devices
+        seed_g = np.asarray(plan.seed_idx)
+        seed_rows, seed_local = cellplan.device_row_maps(seed_g, n_dev)
+        if has_dists:
+            svc_rows, svc_local = cellplan.device_row_maps(
+                np.asarray(plan.dist_id) * n_seeds_total + seed_g, n_dev)
+        else:
+            svc_rows, svc_local = seed_rows, seed_local
 
-    # THIS process's sampling set: the sorted union over its devices
-    # (shared rows are drawn once per host, not once per device)
-    local_pos = rules.local_positions()
-    proc_seed = np.unique(seed_rows[local_pos])
-    proc_svc = np.unique(svc_rows[local_pos])
-    seed_take = {p: np.searchsorted(proc_seed, seed_rows[p])
-                 for p in local_pos}
-    svc_take = {p: np.searchsorted(proc_svc, svc_rows[p])
-                for p in local_pos}
+        # THIS process's sampling set: the sorted union over its devices
+        # (shared rows are drawn once per host, not once per device)
+        local_pos = rules.local_positions()
+        proc_seed = np.unique(seed_rows[local_pos])
+        proc_svc = np.unique(svc_rows[local_pos])
+        seed_take = {p: np.searchsorted(proc_seed, seed_rows[p])
+                     for p in local_pos}
+        svc_take = {p: np.searchsorted(proc_svc, svc_rows[p])
+                    for p in local_pos}
 
-    warmup_start = int(m * warmup_frac)
-    need_hist = len(percentiles) > 0
-    t_chunk, n_chunks, block, pad = queueing._chunk_layout(
-        cfg, chunk_size, need_hist, kernel_on=use_kernel != "off")
-    t_pad = t_chunk + pad
-    r_seed, r_svc = seed_rows.shape[1], svc_rows.shape[1]
+        warmup_start = int(m * warmup_frac)
+        need_hist = len(percentiles) > 0
+        t_chunk, n_chunks, block, pad = queueing._chunk_layout(
+            cfg, chunk_size, need_hist, kernel_on=use_kernel != "off")
+        t_pad = t_chunk + pad
+        r_seed, r_svc = seed_rows.shape[1], svc_rows.shape[1]
 
-    # carry + per-cell plan params as cell-sharded GLOBAL arrays (this
-    # process supplies only its local devices' blocks — required on a
-    # multi-process mesh, a no-op-cost re-layout on one process)
-    put = lambda x: rules.put_cells(np.asarray(x))  # noqa: E731
-    free, ssum, comp, cnt, hist = (
-        put(x) for x in queueing._init_cell_state(plan, cfg, n_bins,
-                                                  need_hist))
-    (seed_local_g, svc_local_g, rates_g, k_mask_g, ovh_g, pol_g, mdl_g,
-     mix_g, pslow_g, sfac_g, pfail_g, delay_g) = (
-        put(x) for x in (seed_local, svc_local, rates_c, k_mask_c, ovh_c,
-                         plan.policy_code, plan.model_code, mix_c,
-                         pslow_c, sfac_c, pfail_c, delay_c))
-    warm_g = rules.put_replicated(np.int32(warmup_start))
-    run_chunk = _body_fn(mesh, cfg.n_servers, n_bins, block, use_kernel,
-                         has_shared, has_timed, has_dists)
+        # carry + per-cell plan params as cell-sharded GLOBAL arrays (this
+        # process supplies only its local devices' blocks — required on a
+        # multi-process mesh, a no-op-cost re-layout on one process)
+        put = lambda x: rules.put_cells(np.asarray(x))  # noqa: E731
+        free, ssum, comp, cnt, hist = (
+            put(x) for x in queueing._init_cell_state(plan, cfg, n_bins,
+                                                      need_hist))
+        (seed_local_g, svc_local_g, rates_g, k_mask_g, ovh_g, pol_g, mdl_g,
+         mix_g, pslow_g, sfac_g, pfail_g, delay_g) = (
+            put(x) for x in (seed_local, svc_local, rates_c, k_mask_c, ovh_c,
+                             plan.policy_code, plan.model_code, mix_c,
+                             pslow_c, sfac_c, pfail_c, delay_c))
+        warm_g = rules.put_replicated(np.int32(warmup_start))
+        run_chunk = _body_fn(mesh, cfg.n_servers, n_bins, block, use_kernel,
+                             has_shared, has_timed, has_dists)
 
     def produce(c: int):
         """Sample THIS host's input rows for chunk ``c`` (one fused
         dispatch) and stage them as per-device cell-sharded blocks."""
-        g, sv, svc = queueing._pad_chunk_inputs(
-            *sampler.rows(c, t_chunk, proc_seed, proc_svc), pad)
-        g, sv, svc = np.asarray(g), np.asarray(sv), np.asarray(svc)
-        return (
-            rules.put_blocks([g[seed_take[p]] for p in local_pos],
-                             (n_dev * r_seed,) + g.shape[1:]),
-            rules.put_blocks([sv[seed_take[p]] for p in local_pos],
-                             (n_dev * r_seed,) + sv.shape[1:]),
-            rules.put_blocks([svc[svc_take[p]] for p in local_pos],
-                             (n_dev * r_svc,) + svc.shape[1:]))
+        with TraceAnnotation("repro.sample", call=call, chunk=c):
+            g, sv, svc = queueing._pad_chunk_inputs(
+                *sampler.rows(c, t_chunk, proc_seed, proc_svc), pad)
+        with TraceAnnotation("repro.stage", call=call, chunk=c):
+            g, sv, svc = np.asarray(g), np.asarray(sv), np.asarray(svc)
+            return (
+                rules.put_blocks([g[seed_take[p]] for p in local_pos],
+                                 (n_dev * r_seed,) + g.shape[1:]),
+                rules.put_blocks([sv[seed_take[p]] for p in local_pos],
+                                 (n_dev * r_seed,) + sv.shape[1:]),
+                rules.put_blocks([svc[svc_take[p]] for p in local_pos],
+                                 (n_dev * r_svc,) + svc.shape[1:]))
 
     use_pipe = pipeline == "on" and n_chunks > 1
+    waits = chunkflow.Waits()
     for c, (gaps_g, servers_g, services_g) in enumerate(
-            chunkflow.iter_staged(produce, n_chunks, enabled=use_pipe)):
+            chunkflow.iter_staged(produce, n_chunks, enabled=use_pipe,
+                                  call=call, waits=waits)):
         start = c * t_chunk
-        free, ssum, comp, cnt, hist = run_chunk(
-            free, ssum, comp, cnt, hist, seed_local_g, rates_g, k_mask_g,
-            ovh_g, pol_g, mdl_g, mix_g, pslow_g, sfac_g, pfail_g, delay_g,
-            svc_local_g, gaps_g, servers_g, services_g,
-            rules.put_replicated(np.int32(start)),
-            rules.put_replicated(np.int32(min(t_chunk, m - start))),
-            warm_g)
+        with TraceAnnotation("repro.chunk.dispatch", call=call, chunk=c):
+            free, ssum, comp, cnt, hist = run_chunk(
+                free, ssum, comp, cnt, hist, seed_local_g, rates_g,
+                k_mask_g, ovh_g, pol_g, mdl_g, mix_g, pslow_g, sfac_g,
+                pfail_g, delay_g, svc_local_g, gaps_g, servers_g,
+                services_g, rules.put_replicated(np.int32(start)),
+                rules.put_replicated(np.int32(min(t_chunk, m - start))),
+                warm_g)
 
-    jax.block_until_ready(ssum)  # drain the producer before stats/gather
+    with TraceAnnotation("repro.drain", call=call):
+        jax.block_until_ready(ssum)  # drain the producer before stats
     queueing._record_pipeline_stats(
         sampler, enabled=use_pipe, n_chunks=n_chunks, t_pad=t_pad,
-        seed_rows=int(proc_seed.size), svc_rows=int(proc_svc.size))
+        seed_rows=int(proc_seed.size), svc_rows=int(proc_svc.size),
+        waits=waits)
 
-    if multihost.spans_processes(mesh):
-        # THE single cross-process gather of the sweep (design note)
-        gathered = multihost.fetch_replicated(
-            mesh, *((ssum, cnt, hist) if need_hist else (ssum, cnt)))
-        ssum, cnt = jnp.asarray(gathered[0]), jnp.asarray(gathered[1])
-        hist = (jnp.asarray(gathered[2]) if need_hist
-                else jnp.zeros((0, 0)))
-    return queueing._finalize_summary(plan, ssum, cnt, hist,
-                                      m - warmup_start, percentiles)
+    with TraceAnnotation("repro.finalize", call=call):
+        if multihost.spans_processes(mesh):
+            # THE single cross-process gather of the sweep (design note)
+            gathered = multihost.fetch_replicated(
+                mesh, *((ssum, cnt, hist) if need_hist else (ssum, cnt)))
+            ssum, cnt = jnp.asarray(gathered[0]), jnp.asarray(gathered[1])
+            hist = (jnp.asarray(gathered[2]) if need_hist
+                    else jnp.zeros((0, 0)))
+        return queueing._finalize_summary(plan, ssum, cnt, hist,
+                                          m - warmup_start, percentiles)
 
 
 def run_sharded(key: Array, scenario, rhos: Array, cfg: queueing.SimConfig,
