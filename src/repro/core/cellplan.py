@@ -17,9 +17,9 @@ also carries its replication-policy and service-model CODES
 mixed-policy grid is just a plan whose cells disagree on those two
 columns — the chunk body branches on them per cell via selects inside
 one compiled scan, and the fused Pallas cell-update kernel
-(``repro.kernels.cell_update``) receives the same codes as
-scalar-prefetch operands, one pair per grid cell, selecting the policy
-arm inside the kernel body with identical select ops. Pad cells alias cell 0's coordinates (including its
+(``repro.kernels.cell_update``) receives the same codes in its per-cell
+parameter block, one lane per cell, selecting the policy arm inside the
+kernel body with identical select ops. Pad cells alias cell 0's coordinates (including its
 policy/model codes) so they simulate real, finite work (no NaN/inf
 poisoning a shared buffer or a collective) but are marked invalid and
 sliced away by ``unflatten`` before any summary is read — a pad cell

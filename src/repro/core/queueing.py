@@ -1054,6 +1054,8 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
     Each call is one ``repro.run`` profiler span, tagged with a
     process-wide engine-call id (``call``) that the engine's inner
     ``repro.*`` spans repeat, and with the call's shape: ``cells``,
+    ``lanes`` (the cell lanes the chunk body computes over all devices,
+    padding included: a multiple of 128 per device on the kernel path),
     ``chunks``, the resolved ``kernel`` and ``pipeline``, ``devices``.
     """
     dist_list, warmup_frac, variants = scenario_mod.combine(scenario)
@@ -1070,12 +1072,15 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
     use_kernel = cell_ops.resolve_kernel_mode(
         kernel, n_bins=n_bins if percentiles else None)
     call = next(_ENGINE_CALLS)
+    n_cells = n_seeds_total * len(rhos) * len(variants)
+    n_dev = 1 if mesh is None else mesh.devices.size
+    per_dev = -(-n_cells // n_dev)
     with TraceAnnotation(
-            "repro.run", call=call,
-            cells=n_seeds_total * len(rhos) * len(variants),
+            "repro.run", call=call, cells=n_cells,
+            lanes=n_dev * (per_dev if use_kernel == "off"
+                           else cell_ops.cell_lanes(per_dev)),
             chunks=_chunk_layout(cfg, chunk_size, need_hist=False)[1],
-            kernel=use_kernel, pipeline=pipeline,
-            devices=1 if mesh is None else mesh.devices.size):
+            kernel=use_kernel, pipeline=pipeline, devices=n_dev):
         rhos = jnp.asarray(rhos)
         k_max = max(v.k for v in variants)
         with_shared = scenario_mod.any_server_dependent(variants)

@@ -101,8 +101,9 @@ from repro.core.distributions import ServiceDist
 
 class Policy(enum.IntEnum):
     """Replication-policy codes (per-cell coordinates in the cell plan;
-    the fused cell-update kernel reads them as scalar-prefetch operands,
-    so the values must stay small non-negative ints)."""
+    the fused cell-update kernel reads them as exact small floats from
+    its per-cell parameter block, so the values must stay small
+    non-negative ints)."""
 
     REPLICATE_ALL = 0
     CANCEL_ON_COMPLETE = 1
@@ -113,8 +114,8 @@ class Policy(enum.IntEnum):
 
 class ServiceModel(enum.IntEnum):
     """Service-model codes (per-cell coordinates in the cell plan; like
-    ``Policy`` codes they ride the fused cell-update kernel as
-    scalar-prefetch operands)."""
+    ``Policy`` codes they ride the fused cell-update kernel's per-cell
+    parameter block)."""
 
     IID = 0
     SERVER_DEPENDENT = 1

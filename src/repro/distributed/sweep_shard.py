@@ -14,9 +14,9 @@ to end:
 
   * Per-cell state is DEVICE-LOCAL for the whole stream: server
     free-time grids, Kahan mean state, and hist_sketch rows live in the
-    local shard of the scan carry, and the Pallas histogram kernel runs
-    per shard on its local (block, C/D) response blocks — the kernel's
-    per-cell grid maps 1:1 onto the sharded axis. Nothing is
+    local shard of the scan carry, and the Pallas kernels run per shard
+    on the shard's own cells (the cell-update kernel lays them on its
+    lanes, the histogram kernel folds their responses). Nothing is
     communicated between chunks.
   * Cell randomness derives from cell COORDINATES, never device
     placement: chunk ``c``, seed ``s`` draws from
@@ -139,9 +139,8 @@ def _body_fn(mesh: jax.sharding.Mesh, n_servers: int, n_bins: int,
 
     ``use_kernel`` is a RESOLVED cell-update kernel mode (see
     ``queueing.run``): the Pallas kernel runs per shard on its local
-    cells — its per-cell grid maps 1:1 onto the sharded axis, like the
-    hist_sketch kernel — so every mode preserves the bit-identity
-    contract.
+    cells, laid on its lanes as on one device, so every mode preserves
+    the bit-identity contract.
     """
     def chunk_body(free, ssum, comp, cnt, hist, seed_idx, rates, k_mask,
                    ovh, policy_code, model_code, mix, p_slow, slow_factor,

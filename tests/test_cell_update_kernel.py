@@ -18,9 +18,11 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import cellplan, distributions as dists, queueing, threshold
+from repro.core import scenario as scenario_mod
 from repro.core.scenario import (CANCEL_ON_COMPLETE, IID, REPLICATE_ALL,
                                  REPLICATE_TO_IDLE, SERVER_DEPENDENT,
-                                 Scenario, combine, variant_codes)
+                                 Degradation, Policy, Scenario, combine,
+                                 variant_codes)
 from repro.kernels.cell_update import ops as cell_ops
 
 CFG = queueing.SimConfig(n_servers=10, n_arrivals=3_000)
@@ -136,42 +138,103 @@ class TestKernelParity:
         assert t_off == t_on
 
 
+def _chunk_carry_parity(key, scns, rhos, cfg, *, n_seeds, pad_to=1):
+    """Drive the chunk body directly, scan against interpreted kernel,
+    on the plan ``queueing.run`` would build for ``scns``: EVERY carry
+    component — free grid, Kahan state, counts, histogram rows, pad
+    rows included — must match bit for bit."""
+    dist_list, warmup_frac, variants = combine(scns)
+    pol, mdl = variant_codes(variants)
+    has_dists = scenario_mod.any_dist_ids(variants)
+    with_shared = scenario_mod.any_server_dependent(variants)
+    with_degr = scenario_mod.any_degraded(variants)
+    plan = cellplan.make_cell_plan(
+        n_seeds, rhos.shape[0], len(variants), pad_to=pad_to, policies=pol,
+        models=mdl, dist_ids=scenario_mod.variant_dist_ids(variants))
+    assert plan.n_padded > plan.n_cells or pad_to == 1
+    params = queueing._plan_cell_params(plan, rhos, cfg, variants)
+    k_max = max(v.k for v in variants)
+    sample = (queueing._dist_table_sampler if has_dists
+              else queueing._sweep_sampler)
+    sampler = sample(key, dist_list if has_dists else dist_list[0], cfg,
+                     k_max, n_seeds, None, with_shared=with_shared,
+                     with_degr=with_degr)
+    pad = (-cfg.n_arrivals) % 512
+    inputs = queueing._pad_chunk_inputs(*sampler(0, cfg.n_arrivals), pad)
+    state = queueing._init_cell_state(plan, cfg, queueing.DEFAULT_BINS,
+                                      True)
+    (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
+     delay_c) = params
+    args = (*state, *inputs, jnp.asarray(0), jnp.asarray(cfg.n_arrivals),
+            jnp.asarray(int(cfg.n_arrivals * warmup_frac)), plan.seed_idx,
+            rates_c, k_mask_c, ovh_c, plan.policy_code, plan.model_code,
+            mix_c, pslow_c, sfac_c, pfail_c, delay_c,
+            plan.dist_id * n_seeds + plan.seed_idx if has_dists else None)
+    kw = dict(n_servers=cfg.n_servers, n_bins=queueing.DEFAULT_BINS,
+              block=512, has_shared=with_shared,
+              has_timed=scenario_mod.any_timed(variants),
+              has_dists=has_dists)
+    out_off = queueing._sweep_chunk_cells(*args, use_kernel="off", **kw)
+    out_on = queueing._sweep_chunk_cells(*args, use_kernel="interpret",
+                                         **kw)
+    for name, a, b in zip(("free", "ssum", "comp", "cnt", "hist"),
+                          out_off, out_on):
+        assert a.shape == b.shape, name
+        assert jnp.array_equal(a, b), name
+    return plan
+
+
+def _timed_degraded(d):
+    # no server-dependent cell: XLA's CPU backend contracts that blend
+    # into a fused multiply-add differently in the two programs (the
+    # kernel module note)
+    degr = Degradation(p_slow=0.1, slow_factor=3.0, p_fail=0.05)
+    return (Scenario(dists=d, policy=Policy.TIMEOUT_RETRY, delay=1.5,
+                     ks=(2, 3), degradation=degr),
+            Scenario(dists=d, policy=Policy.HEDGE_AFTER_DELAY, delay=0.7,
+                     ks=(2,), degradation=degr),
+            Scenario(dists=d, policy=CANCEL_ON_COMPLETE, ks=(2,),
+                     degradation=degr))
+
+
+def _two_systems(d):
+    return (Scenario.paper_default(d, ks=(1, 2)),
+            Scenario(dists=dists.two_point(0.9), policy=REPLICATE_TO_IDLE,
+                     ks=(2,)))
+
+
 class TestPadCellIsolation:
     def test_padded_plan_full_carry_bit_identity(self):
-        # drive the chunk body directly on a plan with pad cells
-        # (n_cells=6 padded to 8): EVERY carry component — free grid,
-        # Kahan state, histogram rows, pad rows included — must match
-        key = jax.random.PRNGKey(6)
+        # a plan with pad cells (n_cells=6 padded to 8): pad lanes in
+        # one sublane row of the kernel's lane layout
         cfg = queueing.SimConfig(n_servers=7, n_arrivals=2_500)
-        rhos = jnp.asarray([0.1, 0.25, 0.4])
-        d = dists.pareto(2.5)
-        _, _, variants = combine(Scenario.paper_default(d, ks=(1, 2)))
-        pol, mdl = variant_codes(variants)
-        plan = cellplan.make_cell_plan(1, 3, 2, pad_to=4, policies=pol,
-                                       models=mdl)
-        assert plan.n_padded > plan.n_cells
-        (rates_c, k_mask_c, ovh_c, mix_c, pslow_c, sfac_c, pfail_c,
-         delay_c) = queueing._plan_cell_params(plan, rhos, cfg, variants)
-        free, ssum, comp, cnt, hist = queueing._init_cell_state(
-            plan, cfg, queueing.DEFAULT_BINS, True)
-        sampler = queueing._sweep_sampler(key, d, cfg, 2, 1, None)
-        pad = (-cfg.n_arrivals) % 512
-        inputs = queueing._pad_chunk_inputs(*sampler(0, cfg.n_arrivals),
-                                            pad)
-        args = (free, ssum, comp, cnt, hist, *inputs, jnp.asarray(0),
-                jnp.asarray(cfg.n_arrivals), jnp.asarray(250),
-                plan.seed_idx, rates_c, k_mask_c, ovh_c,
-                plan.policy_code, plan.model_code, mix_c, pslow_c,
-                sfac_c, pfail_c, delay_c)
-        kw = dict(n_servers=cfg.n_servers, n_bins=queueing.DEFAULT_BINS,
-                  block=512)
-        out_off = queueing._sweep_chunk_cells(*args, use_kernel="off",
-                                              **kw)
-        out_on = queueing._sweep_chunk_cells(*args,
-                                             use_kernel="interpret", **kw)
-        for name, a, b in zip(("free", "ssum", "comp", "cnt", "hist"),
-                              out_off, out_on):
-            assert jnp.array_equal(a, b), name
+        plan = _chunk_carry_parity(
+            jax.random.PRNGKey(6), Scenario.paper_default(
+                dists.pareto(2.5), ks=(1, 2)),
+            jnp.asarray([0.1, 0.25, 0.4]), cfg, n_seeds=1, pad_to=4)
+        assert (plan.n_cells, plan.n_padded) == (6, 8)
+
+    @pytest.mark.parametrize("n_seeds,n_loads,n_arrivals,scns,cells", [
+        # two sublane rows of lanes
+        pytest.param(5, 13, 700, Scenario.paper_default(
+            dists.pareto(2.5), ks=(1, 2)), 130, id="c130"),
+        # two cell blocks of eight rows, the second mostly padding
+        pytest.param(5, 103, 300, Scenario.paper_default(
+            dists.exponential(), ks=(1, 2)), 1030, id="c1030"),
+        # timed policies, degradation columns and the shared draw
+        pytest.param(2, 4, 900, _timed_degraded(dists.exponential()), 32,
+                     id="timed_degraded"),
+        # heterogeneous grid: the service gather routed by dist_id
+        pytest.param(2, 3, 900, _two_systems(dists.exponential()), 18,
+                     id="has_dists"),
+    ])
+    def test_lane_block_full_carry_bit_identity(self, n_seeds, n_loads,
+                                                n_arrivals, scns, cells):
+        cfg = queueing.SimConfig(n_servers=7, n_arrivals=n_arrivals)
+        rhos = jnp.linspace(0.05, 0.45, n_loads)
+        plan = _chunk_carry_parity(jax.random.PRNGKey(11), scns, rhos, cfg,
+                                   n_seeds=n_seeds)
+        assert plan.n_cells == cells
 
 
 class TestDeprecatedShims:

@@ -122,6 +122,28 @@ def test_chunked_and_unchunked_spans(tmp_path):
     assert_same_bits(whole, queueing.run(key, SCN, RHOS, CFG, **KW))
 
 
+def test_run_span_counts_lanes(tmp_path):
+    """``lanes``: the cell lanes the body computes, padding included — a
+    multiple of 128 on the kernel path, the cells themselves on the
+    scan's."""
+    key = jax.random.PRNGKey(4)
+
+    def both():
+        return [jax.block_until_ready(queueing.run(
+            key, SCN, RHOS, CFG, chunk_size=1024, kernel=mode, **KW))
+            for mode in ("interpret", "off")]
+
+    (on, off), sp = traced_spans(tmp_path, both)
+    runs = sorted((s for s in sp if s.name == "repro.run"),
+                  key=lambda s: s.start_ns)
+    assert [r.meta["kernel"] for r in runs] == ["interpret", "off"]
+    kern, scan = (r.meta for r in runs)
+    assert kern["cells"] == scan["cells"] == 2 * 2 * 2
+    assert kern["lanes"] >= kern["cells"] and kern["lanes"] % 128 == 0
+    assert scan["lanes"] == scan["cells"]
+    assert_same_bits(on, off)
+
+
 def test_serial_wait_counts_inline_sampling():
     waits = chunkflow.Waits()
 
@@ -197,4 +219,5 @@ def test_sharded_spans_on_four_devices(tmp_path):
     (run,) = [s for s in sp if s.name == "repro.run"]
     check_call(sp, run, 4, pipelined=True, sharded=True)
     assert run.meta["devices"] == 4 and run.meta["cells"] == 8
+    assert run.meta["lanes"] == 8       # the scan's: 2 cells per device
     assert all(0 <= w < wall for w in got["waits"])

@@ -219,8 +219,8 @@ check("mixed-dists scan vs kernel", het_scan, het_kern)
 check("mixed-dists scan vs sharded", het_scan, het_sh)
 
 # fused cell-update kernel (interpret mode off-TPU), sharded at 8
-# devices: the kernel's per-cell grid maps 1:1 onto the sharded axis,
-# so sharded-kernel == unsharded-kernel == unsharded-scan bits
+# devices: each shard lays its own cells on the kernel's lanes, so
+# sharded-kernel == unsharded-kernel == unsharded-scan bits
 scn = queueing.Scenario.paper_default(dists.exponential(), ks=(1, 2))
 un_scan = queueing.run(key, scn, rhos, cfg, kernel="off",
                        n_seeds=2, chunk_size=2_000)

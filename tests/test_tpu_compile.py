@@ -6,7 +6,8 @@ what interpret mode accepts — blocks whose two minor dims are neither
 (8, 128)-aligned nor the whole array, value-level dynamic slices, too
 much VMEM. The widths are the one-chip smoke run's: 256 cells of 20
 servers, k_max = 2, 8 seed rows (2 service laws x 4 seeds), 2048
-sketch bins, 512-step blocks, a 65536-step chunk.
+sketch bins, 512-step blocks, a 65536-step chunk; the cell-update
+kernel also at 288 and 1030 cells, its other lane-block shapes.
 
 The topology is described inside a module fixture, never while the
 module is imported: only one process at a time may load the TPU
@@ -21,7 +22,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.cell_update.kernel import cell_update_tc
+from repro.kernels.cell_update.ops import cell_update
+from repro.kernels.hist_sketch import ops as hist_sketch_ops
 from repro.kernels.hist_sketch.kernel import hist_accum_tc
 
 C, N, K, ROWS, T, BINS, BLOCK = 256, 20, 2, 8, 65536, 2048, 512
@@ -58,20 +60,22 @@ def _compile(fn, *shapes):
     return compiled
 
 
-def _cell_shapes(sharding, *, n_svc: int, n_bins: int, n_svc_rows: int,
-                 dists: bool):
+def _cell_shapes(sharding, *, n_cells: int, n_svc: int, n_bins: int,
+                 n_svc_rows: int, dists: bool):
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-    per_cell_f32 = [s((C,)) for _ in range(7)]   # rates .. delay
-    shapes = [s((C, N)), s((C,)), s((C,)), s((C,)),
-              s((C, n_bins)) if n_bins else s((0, 0)),
+    c = n_cells
+    shapes = [s((c, N)), s((c,)), s((c,)), s((c,)),
+              s((c, n_bins)) if n_bins else s((0, 0)),
               s((ROWS, T)), s((T,)), s((T,)),
               s((ROWS, T, K), jnp.int32), s((n_svc_rows, T, n_svc)),
-              *(s((C,), jnp.int32) for _ in range(4)),  # seed .. model
-              *per_cell_f32]
+              s((c,), jnp.int32), s((c,)),               # seed, rates
+              s((c, K), jnp.bool_), s((c,)),             # k_mask, ovh
+              s((c,), jnp.int32), s((c,), jnp.int32),    # policy, model
+              *(s((c,)) for _ in range(5))]              # mix .. delay
     if dists:
-        shapes.append(s((C,), jnp.int32))              # svc_idx
+        shapes.append(s((c,), jnp.int32))              # svc_idx
     return shapes
 
 
@@ -80,19 +84,44 @@ def test_hist_accum_compiles(one_chip):
     _compile(lambda i: hist_accum_tc(i, n_bins=BINS, block_t=BLOCK), idx)
 
 
-@pytest.mark.parametrize("layout", [
-    # (n_svc, sketch bins, service-table rows, has_dists, has_shared)
-    pytest.param((K, BINS, ROWS, False, False), id="sketch_on"),
-    pytest.param((K, 0, ROWS, False, False), id="sketch_off"),
-    pytest.param((K, BINS, 2 * ROWS, True, False), id="has_dists"),
+LAYOUTS = [
+    # (n_svc, sketch bins, service-table rows, has_dists, has_shared,
+    #  has_timed)
+    pytest.param((K, BINS, ROWS, False, False, False), id="sketch_on"),
+    pytest.param((K, 0, ROWS, False, False, False), id="sketch_off"),
+    pytest.param((K, BINS, 2 * ROWS, True, False, False), id="has_dists"),
     # timed-policy fault grids: k_max degradation uniforms per copy
-    pytest.param((2 * K, BINS, ROWS, False, False), id="timed_degraded"),
-    pytest.param((2 * K + 1, BINS, ROWS, False, True), id="shared_degraded"),
-])
-def test_cell_update_compiles(one_chip, layout):
-    n_svc, n_bins, n_svc_rows, has_dists, has_shared = layout
-    shapes = _cell_shapes(one_chip, n_svc=n_svc, n_bins=n_bins,
-                          n_svc_rows=n_svc_rows, dists=has_dists)
-    _compile(lambda *a: cell_update_tc(
-        *a, n_servers=N, n_bins=n_bins or BINS, block_t=BLOCK,
-        has_shared=has_shared, has_dists=has_dists), *shapes)
+    pytest.param((2 * K, BINS, ROWS, False, False, True),
+                 id="timed_degraded"),
+    pytest.param((2 * K + 1, BINS, ROWS, False, True, True),
+                 id="shared_degraded"),
+]
+
+
+def _compile_cell_update(sharding, layout, n_cells, monkeypatch):
+    """The kernel path of the chunk body: the wrapper's gather onto the
+    lanes, the kernel, and the histogram fold (whose dispatch asks
+    ``on_tpu``, which sees this host's CPU, so it is steered here)."""
+    monkeypatch.setattr(hist_sketch_ops, "on_tpu", lambda: True)
+    n_svc, n_bins, n_svc_rows, has_dists, has_shared, has_timed = layout
+    shapes = _cell_shapes(sharding, n_cells=n_cells, n_svc=n_svc,
+                          n_bins=n_bins, n_svc_rows=n_svc_rows,
+                          dists=has_dists)
+    _compile(lambda *a: cell_update(
+        *a, n_servers=N, n_bins=n_bins or BINS, block=BLOCK,
+        has_shared=has_shared, has_timed=has_timed,
+        has_dists=has_dists), *shapes)
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_cell_update_compiles(one_chip, layout, monkeypatch):
+    _compile_cell_update(one_chip, layout, C, monkeypatch)
+
+
+# 288 cells: three sublane rows in one cell block; 1030: two cell
+# blocks of eight rows, the last mostly padding
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("n_cells", [288, 1030])
+def test_cell_update_compiles_lane_blocks(one_chip, layout, n_cells,
+                                          monkeypatch):
+    _compile_cell_update(one_chip, layout, n_cells, monkeypatch)
