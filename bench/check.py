@@ -12,7 +12,8 @@ traffic file gives under ``check.limits``:
   [``HIST_LO``, ``HIST_HI``] to its edge bins, so the order statistic is
   clamped alike: a response of 6e17 reads the top bin, as documented;
 - ``completed_gap``: the widest gap of a cell's count of completed
-  requests (exact);
+  requests (exact). A cell in which the reference completes no request
+  reads ``mean_rel`` inf: it has nothing to compare, and fails;
 - ``threshold_gap``: for a bisection query, the gap between its answer
   and the reference's bisection over its own gains.
 
@@ -43,7 +44,10 @@ def compare_summaries(prog: dict, ref: dict) -> dict[str, float]:
     mean = np.asarray(prog["mean"], np.float64)
     pcts = [k for k in ref if k.startswith("p")]
     with np.errstate(divide="ignore", invalid="ignore"):  # 0 reads as inf
-        out["mean_rel"] = _widest(np.abs(mean / ref["mean"] - 1.0).ravel())
+        rel = np.abs(mean / ref["mean"] - 1.0)
+        if "completed" in ref:  # a cell with no completed request fails
+            rel = np.where(np.asarray(ref["completed"]) > 0, rel, np.inf)
+        out["mean_rel"] = _widest(rel.ravel())
         if pcts:
             out["pct_bins"] = _widest(
                 np.abs(np.log(np.asarray(prog[k], np.float64)

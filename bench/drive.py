@@ -108,15 +108,16 @@ def stream_call(grid: reference.Grid, tr: dict,
     """The work of one engine call over ``grid`` with traffic ``tr``."""
     n_seeds = int(tr["n_seeds"])
     n_loads = int(tr["loads"][2]) if n_loads is None else n_loads
-    n_laws = len(grid.laws) if grid.stacked else 1
-    cols = [c[0] for c in grid.columns]
+    reps = (len(grid.laws) if grid.stacked else 1) * n_seeds * n_loads
     m = int(tr["arrivals"])
     return work.Call(
-        ks=tuple(cols) * (n_laws * n_seeds * n_loads),
+        ks=tuple(c.k for c in grid.columns) * reps,
         seed_rows=n_seeds, svc_rows=len(grid.laws) * n_seeds,
-        k_max=max(cols), n_arrivals=m, chunk=tr.get("chunk"),
+        k_max=grid.k_max, n_arrivals=m, chunk=tr.get("chunk"),
         warmup=int(m * grid.warmup_frac), n_servers=grid.n_servers,
-        n_bins=reference.N_BINS if tr.get("percentiles") else 0)
+        n_bins=reference.N_BINS if tr.get("percentiles") else 0,
+        timed=tuple(c.timed for c in grid.columns) * reps,
+        degraded=tuple(c.degraded for c in grid.columns) * reps)
 
 
 class ThresholdQueries:

@@ -50,13 +50,17 @@ def test_trace_without_window_is_refused(tmp_path):
 
 def test_readers_on_recorded_trace(tmp_path):
     """Each per-layer reader of a stream cell reads the recorded trace
-    and stays in range."""
+    and stays in range; the idle readers that split by the program's
+    spans find none in it."""
     import json
 
     from bench import drive, harness, reference, spec
 
     root = bench_testlib.tiny_copy(tmp_path / "b", arrivals=2048, chunk=512)
-    path = tmp_path / "tiny.xplane.pb"
+    # where a run of that checkout writes its trace: the span readers look
+    path = (root / harness.TRACE_DIR / "plugins" / "profile" / "recorded"
+            / "tiny.xplane.pb")
+    path.parent.mkdir(parents=True)
     path.write_bytes(gzip.decompress(RECORDED.read_bytes()))
     cell = spec.load_cell("paper-20srv.tail-sweep", root)
     grid = reference.grid_of(cell.config, spec.reference_laws(cell.config))
@@ -66,7 +70,11 @@ def test_readers_on_recorded_trace(tmp_path):
            for m in cell.per_layer}
     assert set(got) == {"idle_share.stream", "sampler_share.stream",
                         "chunk_body_ns_per_copy_step.stream",
-                        "chunk_body_roofline.stream"}, json.dumps(got)
+                        "chunk_body_roofline.stream",
+                        "idle_input_share.stream",
+                        "idle_finalize_share.stream"}, json.dumps(got)
+    assert got["idle_input_share.stream"] is None
+    assert got["idle_finalize_share.stream"] is None
     assert 0 < got["idle_share.stream"] < 1
     assert 0 < got["sampler_share.stream"] < 1
     assert 150 < got["chunk_body_ns_per_copy_step.stream"] < 250

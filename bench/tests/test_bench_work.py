@@ -40,3 +40,20 @@ def test_counts_follow_the_formulas():
     assert call.bytes == 4 * 1000 * (3 * 3 + 6 * 2) + 2 * 4 * 6 * 2070 * 4
     floor, bound = work.floor_seconds([call], "TPU v5 lite")
     assert bound == "bytes" and floor == call.bytes / 819e9
+
+
+def test_timed_and_degraded_counts():
+    """Timed cells add 3 operations a copy-step (dispatch time, gate),
+    degraded cells 1 (the straggler select); a degraded grid reads one
+    degradation uniform per copy per law and seed row. A copy-step is a
+    copy of the budget, whether or not it fires."""
+    from bench import work
+
+    base = dict(seed_rows=2, svc_rows=2, k_max=2, n_arrivals=1000,
+                chunk=500, warmup=100, n_servers=10, n_bins=0)
+    plain = work.Call(ks=(1, 2, 2), **base)
+    call = work.Call(ks=(1, 2, 2), timed=(False, True, True),
+                     degraded=(True, True, False), **base)
+    assert call.copy_steps == plain.copy_steps == 5000
+    assert call.ops == plain.ops + 1000 * (3 * 4 + 3)
+    assert call.bytes == plain.bytes + 4 * 1000 * 2 * 2

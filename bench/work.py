@@ -6,15 +6,23 @@ of them, whichever body runs them.
 
 The roofline floor of the chunk body charges the work of the semantics:
 
-- operations: per copy-step 3 (start = max(free, arrival), finish =
+- operations: per copy-step 3 (start = max(free, dispatch), finish =
   start + service, the response's min over copies); per arrival and
   cell 3 (arrival time = offset / rate, response = finish - arrival +
   overhead); per post-warm-up arrival and cell 1 for the mean's sum and,
-  where percentiles are asked for, 1 for the histogram increment;
+  where percentiles are asked for, 1 for the histogram increment; per
+  copy-step of a cell with a timed policy (hedge after delay, timeout
+  retry) 3 more (dispatch = arrival + delay * offset, a multiply and an
+  add; the gate against the best finish so far, a compare); per
+  copy-step of a degraded cell 1 more (the straggler select);
 - bytes: each sampled input read once (gaps and copy sets per seed row,
-  one service column per copy per law and seed row, 4 bytes each), and
-  the per-cell carry (free times, sum, count and, with percentiles, the
-  histogram) read and written once per chunk.
+  one service column per copy per law and seed row and, where any cell
+  is degraded, one degradation uniform per copy per law and seed row,
+  4 bytes each), and the per-cell carry (free times, sum, count and,
+  with percentiles, the histogram) read and written once per chunk.
+
+A copy-step of a timed cell is one copy of its budget k, whether or not
+the copy fires.
 
 Nothing an implementation chooses (Kahan terms, one-hot bin adds,
 padding, re-layouts) is counted, so the kernel, the scan and any later
@@ -48,7 +56,9 @@ def device_peaks(device_kind: str) -> dict[str, float]:
 class Call:
     """One engine call as the cell defines it: ``ks`` the replication of
     each cell, ``seed_rows`` the sampled arrival rows, ``svc_rows`` the
-    sampled service rows (laws x seeds), ``k_max`` the copies drawn."""
+    sampled service rows (laws x seeds), ``k_max`` the copies drawn;
+    ``timed`` and ``degraded`` flag each cell with a timed policy or a
+    degradation (empty: none)."""
 
     ks: tuple[int, ...]
     seed_rows: int
@@ -59,6 +69,8 @@ class Call:
     warmup: int
     n_servers: int
     n_bins: int  # 0 without percentiles
+    timed: tuple[bool, ...] = ()
+    degraded: tuple[bool, ...] = ()
 
     @property
     def copy_steps(self) -> int:
@@ -73,14 +85,18 @@ class Call:
     def ops(self) -> float:
         cells = len(self.ks)
         post = self.n_arrivals - self.warmup
+        timed = sum(k for k, t in zip(self.ks, self.timed) if t)
+        degraded = sum(k for k, d in zip(self.ks, self.degraded) if d)
         return float(3 * self.copy_steps + 3 * self.n_arrivals * cells
-                     + (1 + (self.n_bins > 0)) * post * cells)
+                     + (1 + (self.n_bins > 0)) * post * cells
+                     + self.n_arrivals * (3 * timed + degraded))
 
     @property
     def bytes(self) -> float:
         m = self.n_arrivals
+        svc_cols = self.k_max * (1 + any(self.degraded))
         inputs = BYTES * m * (self.seed_rows * (1 + self.k_max)
-                              + self.svc_rows * self.k_max)
+                              + self.svc_rows * svc_cols)
         carry = BYTES * len(self.ks) * (self.n_servers + 2 + self.n_bins)
         return float(inputs + 2 * carry * self.n_chunks)
 
