@@ -1056,7 +1056,10 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
     ``repro.*`` spans repeat, and with the call's shape: ``cells``,
     ``lanes`` (the cell lanes the chunk body computes over all devices,
     padding included: a multiple of 128 per device on the kernel path),
-    ``chunks``, the resolved ``kernel`` and ``pipeline``, ``devices``.
+    ``chunks``, the resolved ``kernel`` and ``pipeline``, ``devices``,
+    the copy budget ``k_max`` (copy slots per lane the body computes),
+    and the cells on a timed policy (``timed``) and with a degradation
+    model (``degraded``).
     """
     dist_list, warmup_frac, variants = scenario_mod.combine(scenario)
     if pipeline not in ("auto", "on", "off"):
@@ -1072,17 +1075,23 @@ def run(key: Array, scenario: scenario_mod.ScenarioLike, rhos: Array,
     use_kernel = cell_ops.resolve_kernel_mode(
         kernel, n_bins=n_bins if percentiles else None)
     call = next(_ENGINE_CALLS)
-    n_cells = n_seeds_total * len(rhos) * len(variants)
+    cells_per_variant = n_seeds_total * len(rhos)
+    n_cells = cells_per_variant * len(variants)
     n_dev = 1 if mesh is None else mesh.devices.size
     per_dev = -(-n_cells // n_dev)
+    k_max = max(v.k for v in variants)
     with TraceAnnotation(
             "repro.run", call=call, cells=n_cells,
             lanes=n_dev * (per_dev if use_kernel == "off"
                            else cell_ops.cell_lanes(per_dev)),
             chunks=_chunk_layout(cfg, chunk_size, need_hist=False)[1],
-            kernel=use_kernel, pipeline=pipeline, devices=n_dev):
+            kernel=use_kernel, pipeline=pipeline, devices=n_dev,
+            k_max=k_max,
+            timed=cells_per_variant * sum(
+                v.policy in scenario_mod.TIMED_POLICIES for v in variants),
+            degraded=cells_per_variant * sum(
+                v.needs_degradation_draw for v in variants)):
         rhos = jnp.asarray(rhos)
-        k_max = max(v.k for v in variants)
         with_shared = scenario_mod.any_server_dependent(variants)
         with_degr = scenario_mod.any_degraded(variants)
         if d == 1:

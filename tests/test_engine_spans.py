@@ -144,6 +144,40 @@ def test_run_span_counts_lanes(tmp_path):
     assert_same_bits(on, off)
 
 
+def test_run_span_counts_copy_budget_and_faults(tmp_path):
+    """``k_max`` (the call's copy budget), ``timed`` (its cells on a timed
+    policy) and ``degraded`` (its cells with a degradation model), on a
+    timed degraded grid and on a bare one; tracing changes no bit."""
+    from repro.core.scenario import Degradation, Policy, Scenario
+
+    exp = (dists.exponential(),)
+    faulty = Degradation(p_fail=0.05, p_slow=0.05, slow_factor=8.0)
+    mixed = (Scenario(dists=exp, ks=(1,)),
+             Scenario(dists=exp, policy=Policy.HEDGE_AFTER_DELAY, ks=(2,),
+                      delay=1.0, degradation=faulty),
+             Scenario(dists=exp, policy=Policy.TIMEOUT_RETRY, ks=(3,),
+                      delay=1.0, degradation=faulty))
+    key = jax.random.PRNGKey(6)
+
+    def both():
+        return [jax.block_until_ready(queueing.run(
+            key, scn, RHOS, CFG, chunk_size=512, **KW))
+            for scn in (mixed, SCN)]
+
+    untraced = both()
+    traced, sp = traced_spans(tmp_path, both)
+    runs = sorted((s for s in sp if s.name == "repro.run"),
+                  key=lambda s: s.start_ns)
+    got = [{k: r.meta[k] for k in ("cells", "k_max", "timed", "degraded")}
+           for r in runs]
+    assert got == [{"cells": 2 * 2 * 3, "k_max": 3, "timed": 2 * 2 * 2,
+                    "degraded": 2 * 2 * 2},
+                   {"cells": 2 * 2 * 2, "k_max": 2, "timed": 0,
+                    "degraded": 0}]
+    for a, b in zip(traced, untraced):
+        assert_same_bits(a, b)
+
+
 def test_serial_wait_counts_inline_sampling():
     waits = chunkflow.Waits()
 

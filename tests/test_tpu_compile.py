@@ -7,7 +7,8 @@ what interpret mode accepts — blocks whose two minor dims are neither
 much VMEM. The widths are the one-chip smoke run's: 256 cells of 20
 servers, k_max = 2, 8 seed rows (2 service laws x 4 seeds), 2048
 sketch bins, 512-step blocks, a 65536-step chunk; the cell-update
-kernel also at 288 and 1030 cells, its other lane-block shapes.
+kernel also at 288 and 1030 cells, its other lane-block shapes, and
+with the fault-masking cell's copy budget of three.
 
 The topology is described inside a module fixture, never while the
 module is imported: only one process at a time may load the TPU
@@ -61,7 +62,7 @@ def _compile(fn, *shapes):
 
 
 def _cell_shapes(sharding, *, n_cells: int, n_svc: int, n_bins: int,
-                 n_svc_rows: int, dists: bool):
+                 n_svc_rows: int, dists: bool, k_max: int):
     def s(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -69,9 +70,9 @@ def _cell_shapes(sharding, *, n_cells: int, n_svc: int, n_bins: int,
     shapes = [s((c, N)), s((c,)), s((c,)), s((c,)),
               s((c, n_bins)) if n_bins else s((0, 0)),
               s((ROWS, T)), s((T,)), s((T,)),
-              s((ROWS, T, K), jnp.int32), s((n_svc_rows, T, n_svc)),
+              s((ROWS, T, k_max), jnp.int32), s((n_svc_rows, T, n_svc)),
               s((c,), jnp.int32), s((c,)),               # seed, rates
-              s((c, K), jnp.bool_), s((c,)),             # k_mask, ovh
+              s((c, k_max), jnp.bool_), s((c,)),         # k_mask, ovh
               s((c,), jnp.int32), s((c,), jnp.int32),    # policy, model
               *(s((c,)) for _ in range(5))]              # mix .. delay
     if dists:
@@ -86,15 +87,19 @@ def test_hist_accum_compiles(one_chip):
 
 LAYOUTS = [
     # (n_svc, sketch bins, service-table rows, has_dists, has_shared,
-    #  has_timed)
-    pytest.param((K, BINS, ROWS, False, False, False), id="sketch_on"),
-    pytest.param((K, 0, ROWS, False, False, False), id="sketch_off"),
-    pytest.param((K, BINS, 2 * ROWS, True, False, False), id="has_dists"),
+    #  has_timed, k_max)
+    pytest.param((K, BINS, ROWS, False, False, False, K), id="sketch_on"),
+    pytest.param((K, 0, ROWS, False, False, False, K), id="sketch_off"),
+    pytest.param((K, BINS, 2 * ROWS, True, False, False, K),
+                 id="has_dists"),
     # timed-policy fault grids: k_max degradation uniforms per copy
-    pytest.param((2 * K, BINS, ROWS, False, False, True),
+    pytest.param((2 * K, BINS, ROWS, False, False, True, K),
                  id="timed_degraded"),
-    pytest.param((2 * K + 1, BINS, ROWS, False, True, True),
+    pytest.param((2 * K + 1, BINS, ROWS, False, True, True, K),
                  id="shared_degraded"),
+    # the fault-masking cell's: a three-attempt retry budget
+    pytest.param((2 * 3, BINS, ROWS, False, False, True, 3),
+                 id="timed_degraded_k3"),
 ]
 
 
@@ -103,10 +108,11 @@ def _compile_cell_update(sharding, layout, n_cells, monkeypatch):
     lanes, the kernel, and the histogram fold (whose dispatch asks
     ``on_tpu``, which sees this host's CPU, so it is steered here)."""
     monkeypatch.setattr(hist_sketch_ops, "on_tpu", lambda: True)
-    n_svc, n_bins, n_svc_rows, has_dists, has_shared, has_timed = layout
+    (n_svc, n_bins, n_svc_rows, has_dists, has_shared, has_timed,
+     k_max) = layout
     shapes = _cell_shapes(sharding, n_cells=n_cells, n_svc=n_svc,
                           n_bins=n_bins, n_svc_rows=n_svc_rows,
-                          dists=has_dists)
+                          dists=has_dists, k_max=k_max)
     _compile(lambda *a: cell_update(
         *a, n_servers=N, n_bins=n_bins or BINS, block=BLOCK,
         has_shared=has_shared, has_timed=has_timed,
